@@ -63,11 +63,11 @@ def run_engine(cfg, serve_cfg, data, bursts):
 # Engine start is compile-dominated (init + warmup >> serve).  The JAX
 # persistent compilation cache makes every restart after the first read its
 # executables off disk; since jit's in-memory cache would mask that inside
-# one process, the demonstration runs this same script twice as a
-# subprocess against a shared --cache-dir and compares init+warmup.
+# one process, the demonstration runs this same script twice as a CPU
+# subprocess against a shared JAX_COMPILATION_CACHE_DIR and compares
+# init+warmup.
 
-def _inner_probe(cache_dir: str) -> None:
-    os.environ["REPRO_COMPILE_CACHE_DIR"] = cache_dir
+def _inner_probe() -> None:
     spec = ds.DatasetSpec("warm", n=400, dim=8, universe=32, num_clusters=4)
     cfg = IndexConfig(num_tables=2, num_hashes=6, width=16, num_probes=10,
                       candidate_cap=8, universe=32, k=4, rerank_chunk=64)
@@ -89,15 +89,16 @@ def warm_start_demo() -> dict:
         runs = []
         for _ in range(2):
             out = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--inner-probe",
-                 "--cache-dir", cache_dir],
+                [sys.executable, os.path.abspath(__file__), "--inner-probe"],
                 capture_output=True, text=True, check=True,
-                env={**os.environ, "JAX_PLATFORMS": "cpu"})
+                env={**os.environ, "JAX_PLATFORMS": "cpu",
+                     "JAX_COMPILATION_CACHE_DIR": cache_dir})
             runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
     cold, warm = runs
     cold_total = cold["init_ms"]
     warm_total = warm["init_ms"]
     return {
+        "platform": "cpu",
         "cold": cold,
         "warm": warm,
         "startup_speedup": round(cold_total / max(warm_total, 1e-9), 2),
@@ -237,10 +238,9 @@ if __name__ == "__main__":
                     help="skip the 2-subprocess persistent-cache demo")
     ap.add_argument("--inner-probe", action="store_true",
                     help=argparse.SUPPRESS)  # warm_start_demo child mode
-    ap.add_argument("--cache-dir", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.inner_probe:
-        _inner_probe(args.cache_dir)
+        _inner_probe()
     else:
         main(smoke=args.smoke, json_out=args.json_out,
              skip_warm_start=args.skip_warm_start)

@@ -53,15 +53,31 @@ __all__ = ["RemoteReplica", "WorkerHandle", "spawn_replica_grid"]
 
 
 def _worker_env() -> dict:
-    """Subprocess env: the worker must import ``repro`` from this checkout
-    and must not race the parent for an accelerator."""
+    """Subprocess env: the worker imports ``repro`` from this checkout and
+    inherits this process's ``JAX_PLATFORMS``.
+
+    An accelerator belongs to one process at a time, and the router (this
+    process) runs JAX itself — the cross-shard merge.  So unless the
+    workers are pinned to the CPU, a worker on an accelerator host could
+    only fail or hang waiting for a chip this process holds; that is
+    refused here, before any worker starts.  Serve from one process on
+    such a host (the in-process router, or the engine alone).
+    """
     env = dict(os.environ)
+    if env.get("JAX_PLATFORMS", "").lower() != "cpu":
+        import jax
+        backend = jax.default_backend()
+        if backend != "cpu":
+            raise RuntimeError(
+                f"refusing to start worker processes: this process holds "
+                f"the {backend} device, which a worker cannot share.  Use "
+                f"the in-process router (transport='inproc'), or set "
+                f"JAX_PLATFORMS=cpu to serve the workers on the CPU.")
     import repro
     src = os.path.dirname(os.path.dirname(os.path.abspath(repro.__file__)))
     parts = [src] + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
                      if p and p != src]
     env["PYTHONPATH"] = os.pathsep.join(parts)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     return env
 
 

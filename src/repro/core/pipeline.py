@@ -541,10 +541,12 @@ def stage_merge_pair(
     """Merge two ascending (Q, k) top-k lists into one.
 
     Invalid entries must carry dist >= BIG_DIST (id -1 or sentinel).  With
-    ``use_kernel`` the bitonic Pallas ``topk_merge`` runs (the same kernel
-    the distributed ring merge uses); the fallback is a lexicographic
-    concat sort.  Both backends tie-break on (dist, id), so they return
-    identical ids even on tied distances.
+    ``use_kernel`` the merge runs ``kops.topk_merge`` (the same op the
+    distributed ring merge uses), whose executor is chosen per backend
+    (``kops.executors``: the bitonic Pallas kernel off TPU, the
+    lexicographic sort merge on TPU); without it, the lexicographic concat
+    sort.  All of them tie-break on (dist, id), so they return identical
+    ids even on tied distances.
     """
     if use_kernel:
         return kops.topk_merge(da, ia, db, ib)

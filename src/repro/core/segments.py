@@ -20,8 +20,8 @@ All query work is statically shaped and jit-compiled: the delta buffer has
 a fixed capacity (padded; a row count masks the tail), tombstones live in a
 power-of-two device array padded with INT32_MAX (the pad matches no real
 gid, so no count is carried), and the per-segment top-k lists are folded
-with the same bitonic ``topk_merge`` kernel the distributed ring merge
-uses — the single-host path exercises the distributed merge machinery.
+with the same ``topk_merge`` op the distributed ring merge uses — the
+single-host path exercises the distributed merge machinery.
 
 Every segment shares one ``LshParams`` (the paper's fixed cost, Sect. 3.2):
 a point hashes to the same buckets whichever segment holds it, which is

@@ -95,13 +95,17 @@ def make_skewed_dataset(spec: DatasetSpec, zipf_s: float = 1.4,
 
 def make_queries(
     spec: DatasetSpec, dataset: np.ndarray, num_queries: int,
-    perturb_frac: float = 0.02, seed: int = 1,
+    perturb_frac: float = 0.02, seed: int = 1, stray_frac: float = 0.1,
 ) -> np.ndarray:
-    """Queries near real points (controlled L1 offsets) + 10% uniform strays."""
+    """Queries near real points (controlled L1 offsets) + ``stray_frac``
+    uniform strays (default 10%).  A stray's neighbors are all about
+    equally far, so LSH finds almost none of them; ``stray_frac=0`` keeps
+    every query in the data's distribution, as a benchmark's held-out
+    query set is."""
     rng = np.random.default_rng(seed + spec.seed)
     base = dataset[rng.integers(0, dataset.shape[0], size=num_queries)].astype(np.float64)
     base += rng.laplace(0.0, perturb_frac * spec.universe, size=base.shape)
-    stray = rng.random(size=num_queries) < 0.1
+    stray = rng.random(size=num_queries) < stray_frac
     base[stray] = rng.uniform(0, spec.universe, size=(stray.sum(), spec.dim))
     even = 2 * np.round(base / 2.0)
     return np.clip(even, 0, spec.universe).astype(np.int32)

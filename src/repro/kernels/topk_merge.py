@@ -34,6 +34,7 @@ __all__ = [
     "bitonic_topk_merge_rows",
     "bitonic_sort_rows",
     "topk_merge_pallas",
+    "topk_merge_xla",
 ]
 
 
@@ -152,3 +153,15 @@ def topk_merge_pallas(
         interpret=interpret,
     )(da, ia, db, ib)
     return do[:q, :k], io[:q, :k]
+
+
+@jax.jit
+def topk_merge_xla(da: jax.Array, ia: jax.Array, db: jax.Array,
+                   ib: jax.Array):
+    """Same contract as ``topk_merge_pallas`` in XLA ops: one
+    lexicographic (dist, id) sort of the concatenated lists."""
+    k = da.shape[-1]
+    d, i = jax.lax.sort((jnp.concatenate([da, db], axis=-1),
+                         jnp.concatenate([ia, ib], axis=-1)),
+                        dimension=-1, num_keys=2)
+    return d[:, :k], i[:, :k]

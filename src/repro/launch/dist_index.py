@@ -19,8 +19,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import hashes as hashes_lib
 from repro.core import pipeline as pipe
@@ -86,7 +86,7 @@ def dist_build_fn(cfg: IndexConfig, mesh: Mesh):
         in_specs=(P(rows, None), P()),
         out_specs=(P(None, rows), P(None, rows), P(rows), P(None, rows),
                    P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def build(dataset, params):
@@ -178,7 +178,7 @@ def dist_query_fn(cfg: IndexConfig, mesh: Mesh, merge: str = "allgather",
     )
     fn = shard_map(local_query, mesh=mesh, in_specs=in_specs,
                    out_specs=(P("model", None), P("model", None)),
-                   check_rep=False)
+                   check_vma=False)
 
     def query(state: IndexState, queries):
         return fn(state.sorted_keys, state.sorted_ids, state.dataset,

@@ -21,6 +21,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--dim", type=int, default=64)
+    ap.add_argument("--universe", type=int, default=128,
+                    help="max (even) coordinate U; 510 is SIFT's shape")
     ap.add_argument("--queries", type=int, default=256)
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--tables", type=int, default=8)
@@ -32,7 +34,7 @@ def main(argv=None):
                          "instead of serving --tables/--probes as given")
     args = ap.parse_args(argv)
 
-    spec = ds.DatasetSpec("serve", n=args.n, dim=args.dim, universe=128,
+    spec = ds.DatasetSpec("serve", n=args.n, dim=args.dim, universe=args.universe,
                           num_clusters=32)
     data = ds.make_dataset(spec)
     queries = ds.make_queries(spec, data, args.queries)

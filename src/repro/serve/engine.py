@@ -16,8 +16,8 @@ Production posture on a single process:
     multi-replica deployment runs it on the background thread pool
     (DESIGN.md Sect. 3);
   * queries probe every segment with the staged pipeline and fold the
-    per-segment top-k lists with the same bitonic ``topk_merge`` kernel
-    the distributed ring merge uses;
+    per-segment top-k lists with the same ``topk_merge`` op the
+    distributed ring merge uses (executor per backend, DESIGN.md §5.4);
   * per-batch deadline timing + straggler hedging hook: if a batch misses
     the hedge deadline the event is recorded in ``stats['hedges']``; the
     cluster runtime (``repro.cluster``, DESIGN.md §7) turns this into a real
@@ -38,6 +38,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.compilation_cache import (
+    compilation_cache as cc_public)
 
 from repro.analysis import racecheck
 from repro.core.index import IndexConfig, IndexState
@@ -53,15 +55,25 @@ __all__ = ["ServeConfig", "AnnServingEngine", "enable_compilation_cache",
 # --------------------------------------------------------------------------
 # Persistent compilation cache (DESIGN.md §8)
 # --------------------------------------------------------------------------
-# Cold engine start is compile-dominated (BENCH_serving.json: ~14 s init +
-# ~9 s warmup vs ~1.5 s of actual serving).  The executables depend only on
+# Cold engine start is compile-dominated: warmup compiles every (batch
+# bucket x candidate rung) executable.  The executables depend only on
 # (config, shapes), so the JAX persistent compilation cache turns every
 # restart after the first into disk reads.  Enabled once per process; the
 # hit/miss counters come from jax.monitoring events and are surfaced in
 # ``AnnServingEngine.summary()`` so operators can verify warm starts
-# actually hit.
+# actually hit.  The directory is placed from outside: where
+# ``JAX_COMPILATION_CACHE_DIR`` is set, JAX keeps the cache there and no
+# directory is set in code; otherwise it is ``<repo>/.jax_cache``, one
+# fixed path inside the checkout (the path is part of what lets a later
+# process find an entry, so it must not move between runs).
 
-_CACHE_STATS = {"enabled": False, "dir": None, "hits": 0, "misses": 0}
+REPO_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+_CACHE_STATS = {"enabled": False, "dir": None, "hits": 0, "misses": 0,
+                "atomic_writes": False}
+_LISTENING = False
 
 
 def _cache_listener(event: str, **_kw) -> None:
@@ -71,7 +83,7 @@ def _cache_listener(event: str, **_kw) -> None:
         _CACHE_STATS["misses"] += 1
 
 
-def _install_atomic_cache_writes() -> None:
+def _install_atomic_cache_writes() -> bool:
     """Make jax's on-disk cache writes atomic (write-temp + os.replace).
 
     ``LRUCache.put`` writes cache files with a bare ``write_bytes`` and,
@@ -83,72 +95,80 @@ def _install_atomic_cache_writes() -> None:
     same-directory temp file and ``os.replace``-ing it into place means
     readers see the old entry, the complete new one, or a miss — never a
     prefix; the original ``put`` then hits its entry-already-exists early
-    return.  Private API: any failure leaves the stock behavior in place.
+    return.  Private API: when its layout is not the one this patch knows,
+    the stock behavior stays, a warning says so, and False is returned.
     """
+    import tempfile
+    import warnings
+
     try:
-        import tempfile
-
         from jax._src import lru_cache as _lru
+    except ImportError as err:
+        warnings.warn(f"compile cache writes stay non-atomic: {err}")
+        return False
+    if not (hasattr(_lru, "LRUCache") and hasattr(_lru, "_CACHE_SUFFIX")):
+        warnings.warn("compile cache writes stay non-atomic: "
+                      "jax._src.lru_cache has an unknown layout")
+        return False
+    if getattr(_lru.LRUCache.put, "_repro_atomic", False):
+        return True
+    orig_put = _lru.LRUCache.put
+    cache_suffix = _lru._CACHE_SUFFIX
 
-        if getattr(_lru.LRUCache.put, "_repro_atomic", False):
-            return
-        orig_put = _lru.LRUCache.put
-        cache_suffix = _lru._CACHE_SUFFIX
-
-        def atomic_put(self, key, val):
-            if key and not self.eviction_enabled:
-                try:
-                    cache_path = self.path / f"{key}{cache_suffix}"
-                    if not cache_path.exists():
-                        fd, tmp = tempfile.mkstemp(
-                            dir=str(self.path), suffix=".tmp")
+    def atomic_put(self, key, val):
+        if key and not self.eviction_enabled:
+            try:
+                cache_path = self.path / f"{key}{cache_suffix}"
+                if not cache_path.exists():
+                    fd, tmp = tempfile.mkstemp(
+                        dir=str(self.path), suffix=".tmp")
+                    try:
+                        with os.fdopen(fd, "wb") as f:
+                            f.write(val)
+                        os.replace(tmp, cache_path)
+                    except BaseException:
                         try:
-                            with os.fdopen(fd, "wb") as f:
-                                f.write(val)
-                            os.replace(tmp, cache_path)
-                        except BaseException:
-                            try:
-                                os.unlink(tmp)
-                            except OSError:
-                                pass
-                            raise
-                except OSError:
-                    pass          # cache write trouble is never fatal
-            return orig_put(self, key, val)
+                            os.unlink(tmp)
+                        except OSError:
+                            pass
+                        raise
+            except OSError:
+                pass          # cache write trouble is never fatal
+        return orig_put(self, key, val)
 
-        atomic_put._repro_atomic = True
-        _lru.LRUCache.put = atomic_put
-    except Exception:
-        pass
+    atomic_put._repro_atomic = True
+    _lru.LRUCache.put = atomic_put
+    return True
 
 
-def enable_compilation_cache(path: Optional[str] = None) -> dict:
-    """Point JAX's persistent compilation cache at ``path`` (idempotent).
+def enable_compilation_cache() -> dict:
+    """Turn on JAX's persistent compilation cache (idempotent).
 
-    Default dir: ``$REPRO_COMPILE_CACHE_DIR`` or ``~/.cache/repro-jax-cache``.
-    Returns the live stats dict (also via ``compilation_cache_stats()``).
+    Uses ``$JAX_COMPILATION_CACHE_DIR`` when it is set (and then sets no
+    directory in code), else ``REPO_CACHE_DIR``.  Returns the live stats
+    dict (also via ``compilation_cache_stats()``).
     """
+    global _LISTENING
     if _CACHE_STATS["enabled"]:
         return _CACHE_STATS
-    path = (path or os.environ.get("REPRO_COMPILE_CACHE_DIR")
-            or os.path.expanduser("~/.cache/repro-jax-cache"))
-    os.makedirs(path, exist_ok=True)
-    _install_atomic_cache_writes()
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = REPO_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    atomic = _install_atomic_cache_writes()
     # serving executables are small and numerous; cache all of them
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # jax's "is the cache used" probe latches on the FIRST compile of
-        # the process; any jit that ran before this config lands (dataset
-        # prep, index build) would silently disable caching for the whole
-        # process.  reset_cache() re-evaluates the gate under the new dir.
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass  # private API; worst case the cache stays in its latched state
-    jax.monitoring.register_event_listener(_cache_listener)
-    _CACHE_STATS.update(enabled=True, dir=path)
+    # jax's "is the cache used" probe latches on the FIRST compile of the
+    # process; any jit that ran before this config lands (dataset prep,
+    # index build) would otherwise leave caching off for the whole process.
+    # reset_cache() re-evaluates the gate under the new settings.
+    cc_public.reset_cache()
+    if not _LISTENING:
+        jax.monitoring.register_event_listener(_cache_listener)
+        _LISTENING = True
+    _CACHE_STATS.update(enabled=True, dir=path, atomic_writes=atomic)
     return _CACHE_STATS
 
 
@@ -179,8 +199,7 @@ class ServeConfig:
                                    # candidate totals
     persistent_cache: bool = True  # JAX persistent compilation cache: warm
                                    # restarts read executables off disk
-    cache_dir: Optional[str] = None  # None -> $REPRO_COMPILE_CACHE_DIR or
-                                   # ~/.cache/repro-jax-cache
+                                   # (directory: enable_compilation_cache)
     hedge_ms: float = 50.0
     max_wait_ms: float = 2.0
     delta_cap: int = 1024          # delta-buffer capacity (points)
@@ -257,7 +276,7 @@ class AnnServingEngine:
         self.serve_cfg = serve_cfg
         if serve_cfg.persistent_cache:
             # before the first compile so warmup itself can hit the cache
-            enable_compilation_cache(serve_cfg.cache_dir)
+            enable_compilation_cache()
         key = key if key is not None else jax.random.PRNGKey(0)
         self.autotune = None
         if index is not None:

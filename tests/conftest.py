@@ -1,10 +1,17 @@
 import os
 import sys
+import tempfile
 import types
 
 # Smoke tests and benches must see ONE device; only launch/dryrun.py (run as
 # its own process) forces 512 placeholder devices.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# One fixed persistent compile cache outside the checkout (under the temp
+# dir), set before jax is imported: test runs then never grow the tree,
+# and the engine sets no cache directory of its own.
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    os.path.join(tempfile.gettempdir(), "repro-jax-cache"))
 
 import numpy as np
 import pytest

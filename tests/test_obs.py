@@ -316,6 +316,36 @@ def test_compile_cache_writes_are_atomic(tmp_path, monkeypatch):
     assert not list(tmp_path.glob("*.tmp"))
 
 
+def test_compile_cache_dir_is_placed_from_outside(tmp_path, monkeypatch):
+    # $JAX_COMPILATION_CACHE_DIR set (conftest sets one outside the
+    # checkout): the engine leaves jax's directory there.  Unset: the one
+    # fixed path inside the checkout, <repo>/.jax_cache.
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.serve import engine as engine_mod
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert engine_mod.REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    outside = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setitem(engine_mod._CACHE_STATS, "enabled", False)
+    monkeypatch.setitem(engine_mod._CACHE_STATS, "dir", None)
+    try:
+        assert engine_mod.enable_compilation_cache()["dir"] == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        inside = str(tmp_path / ".jax_cache")
+        monkeypatch.setattr(engine_mod, "REPO_CACHE_DIR", inside)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        engine_mod._CACHE_STATS["enabled"] = False
+        assert engine_mod.enable_compilation_cache()["dir"] == inside
+        assert jax.config.jax_compilation_cache_dir == inside
+        assert os.path.isdir(inside)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
+
+
 def test_engine_summary_is_histogram_backed(cfg, small):
     data, queries = small
     eng = AnnServingEngine(

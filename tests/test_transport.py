@@ -429,6 +429,20 @@ def test_pack_unpack_records_roundtrip():
 # --------------------------------------------- worker process integration
 
 
+def test_worker_env_inherits_platform_and_refuses_a_held_chip(monkeypatch):
+    # workers inherit the parent's JAX_PLATFORMS (no CPU pin of their own);
+    # where the parent would hold an accelerator, spawning is refused
+    from repro.cluster import remote
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert remote._worker_env()["JAX_PLATFORMS"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "JAX_PLATFORMS" not in remote._worker_env()   # CPU-only host
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="in-process router"):
+        remote._worker_env()
+
+
 def test_remote_replica_bit_identical_and_sigkill_recovery(
         cfg, small, tmp_path):
     """One worker subprocess == one in-process replica, bit for bit: same
