@@ -263,11 +263,16 @@ def probe_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
     for the Pallas executor, which re-searches in VMEM instead (each
     backend's unused input is dead-code-eliminated).
     """
-    bucket, x_neg = pipe.stage_hash(cfg, state.params, queries)
-    probe_keys = pipe.stage_probe_keys(
-        cfg, state.params, state.template, bucket, x_neg)
-    lo, occ, counts = pipe.stage_probe_extents(
-        cfg, state.sorted_keys, probe_keys, state.occ_from)
+    # The scopes name this program's device ops in a profiler trace; the
+    # counts' own scope is inside the extents (``counts``).
+    with jax.named_scope("hash"):
+        bucket, x_neg = pipe.stage_hash(cfg, state.params, queries)
+    with jax.named_scope("probe_keys"):
+        probe_keys = pipe.stage_probe_keys(
+            cfg, state.params, state.template, bucket, x_neg)
+    with jax.named_scope("extents"):
+        lo, occ, counts = pipe.stage_probe_extents(
+            cfg, state.sorted_keys, probe_keys, state.occ_from)
     return probe_keys, lo, occ, counts
 
 

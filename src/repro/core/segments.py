@@ -148,16 +148,22 @@ def _finish_segment(cfg: IndexConfig, cbucket: int, c_cap: Optional[int],
     DESIGN.md §9).
     """
     n = state.dataset.shape[0]
-    ids, _ = pipe.stage_fused_probe(
-        cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
-        extents=(lo, occ), c_cap=c_cap)
+    # The scopes name this program's device ops in a profiler trace.
+    with jax.named_scope("compact_gather"):
+        ids, _ = pipe.stage_fused_probe(
+            cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
+            extents=(lo, occ), c_cap=c_cap)
     if not pipe.rerank_handles_duplicates(cfg):
-        ids = pipe.stage_dedup(ids, n)
-    ids = pipe.stage_tombstone(ids, gids, tombstones, n)
-    d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
+        with jax.named_scope("dedup"):
+            ids = pipe.stage_dedup(ids, n)
+    with jax.named_scope("tombstone"):
+        ids = pipe.stage_tombstone(ids, gids, tombstones, n)
+    with jax.named_scope("rerank"):
+        d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
     if n == 0:
         return d, i
-    gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, n - 1)], -1)
+    with jax.named_scope("gid_map"):
+        gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, n - 1)], -1)
     return d, gid
 
 
@@ -603,12 +609,6 @@ class SegmentedIndex:
         queries = jnp.asarray(queries)
         tomb = self._tombstone_array()
         results, used = [], []
-        # tracing note (DESIGN.md §12): with REPRO_TRACE=1 each phase
-        # blocks at its span boundary so the recorded durations attribute
-        # real device time to phase A vs phase B instead of measuring
-        # async dispatch; tracing OFF leaves the pipelining untouched
-        # (span() is a shared no-op and no extra sync happens).
-        traced = obs_trace.enabled()
         for seg in self.segments:
             if seg.size == 0:
                 # no probe front-end to compact; the stock path already
@@ -620,17 +620,20 @@ class SegmentedIndex:
             with obs_trace.span("phase_a", segment=int(seg.size)):
                 probe_keys, lo, occ, counts = _probe_segment(
                     self.cfg, seg.state, queries)
+            # the one host read of a batch: it waits for phase A, runs the
+            # ``jit__reduce_max`` program and copies its scalar back
+            with obs_trace.span("rung_pick") as sp:
+                max_count = int(counts.max())  # repro: allow[r1-host-sync] THE sanctioned phase-A rung-pick read (DESIGN.md §8)
                 cb, c_cap, over = pipe.pick_rung(
-                    int(counts.max()), seg.ctot_cap, floor,  # repro: allow[r1-host-sync] THE sanctioned phase-A rung-pick read (DESIGN.md §8)
+                    max_count, seg.ctot_cap, floor,
                     seg.ctot_norm, seg.c_norm, overflow)
+                sp.set(rung=cb, max_count=max_count, c_cap=c_cap)
             with obs_trace.span("phase_b_rerank", segment=int(seg.size),
                                 cbucket=int(cb),
                                 c_cap=None if c_cap is None else int(c_cap)):
                 res = _finish_segment(
                     self.cfg, cb, c_cap, seg.state, seg.gids, tomb,
                     probe_keys, lo, occ, queries)
-                if traced:
-                    res[0].block_until_ready()
             results.append(res)
             used.append((seg.size, cb, c_cap))
             if stats is not None and over:
@@ -650,8 +653,6 @@ class SegmentedIndex:
             for dn, in_ in results[1:]:
                 d, i = pipe.stage_merge_pair(d, i, dn, in_,
                                              use_kernel=use_merge_kernel)
-            if traced:
-                d.block_until_ready()
         return d, i, tuple(used)
 
     def warm_compact(self, queries: jax.Array, floor: int = 64,

@@ -262,7 +262,8 @@ def probe_extents_xla(sorted_keys: jax.Array, probe_keys: jax.Array,
         hit = (jnp.take(sorted_keys.reshape(-1), safe) == pk_flat) & (lo < n)
         occ = jnp.where(hit, jnp.take(occ_from.reshape(-1), safe),
                         0).astype(jnp.int32)
-    counts = jnp.minimum(occ, cap).sum(axis=-1).astype(jnp.int32)
+    with jax.named_scope("counts"):
+        counts = jnp.minimum(occ, cap).sum(axis=-1).astype(jnp.int32)
     return lo, occ, counts
 
 

@@ -24,6 +24,16 @@ dict lookup and nothing else.  With tracing on:
 thread's spans into a thread-local list — the flight recorder uses this
 to attach the full span tree to slow-query exemplars without re-reading
 the files.
+
+A second sink needs no ``REPRO_TRACE``: while a JAX profiler session
+records (``jax.profiler.start_trace``), every ``span(name, **attrs)`` also
+opens ``jax.profiler.TraceAnnotation("repro." + name)`` for its duration,
+with its scalar attributes (those given and those ``set()`` later) as the
+event's stats.  Those host events share the clock of the device's ops in
+the profiler's trace, so a device idle gap can be put down to the span the
+host was in.  This module never imports jax: it looks the profiler up in
+``sys.modules`` once jax is loaded, and with no session recording the
+check costs one ``TraceMe.is_enabled()`` call per span.
 """
 from __future__ import annotations
 
@@ -31,6 +41,7 @@ import atexit
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -123,6 +134,60 @@ def flush() -> None:
             f.write(json.dumps(r) + "\n")
 
 
+PROFILER_PREFIX = "repro."       # name prefix of spans in the profiler's trace
+_annotation = None               # jax.profiler.TraceAnnotation, once found
+
+
+def _find_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is loaded, else None.
+    Looks in ``sys.modules`` only, so that this package never imports jax."""
+    global _annotation
+    mod = sys.modules.get("jax.profiler")
+    _annotation = getattr(mod, "TraceAnnotation", None)
+    return _annotation
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a JAX profiler session records,
+    else None."""
+    ann = _annotation or _find_annotation()
+    return ann if ann is not None and ann.is_enabled() else None
+
+
+def _stats(attrs: dict) -> dict:
+    """The attributes a profiler event can carry as stats: scalars."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (int, float, str))}
+
+
+def _profiler_event(ann, name: str, attrs: dict):
+    """Start the profiler event of span ``name`` (a ``TraceMe`` records from
+    its construction); returns it, for ``__exit__`` to end."""
+    event = ann(PROFILER_PREFIX + name, **_stats(attrs))
+    event.__enter__()
+    return event
+
+
+class _ProfilerSpan:
+    """A span written to the JAX profiler's trace only (``REPRO_TRACE`` off)."""
+    __slots__ = ("_ann", "_name", "_attrs", "_event")
+
+    def __init__(self, ann, name: str, attrs: dict):
+        self._ann, self._name, self._attrs = ann, name, attrs
+
+    def __enter__(self):
+        self._event = _profiler_event(self._ann, self._name, self._attrs)
+        return self
+
+    def __exit__(self, *exc):
+        self._event.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        self._event.set_metadata(**_stats(attrs))
+        return self
+
+
 class _NullSpan:
     """Shared tracing-off stand-in: no state, no clock, no allocation."""
     __slots__ = ()
@@ -142,7 +207,7 @@ _NULL = _NullSpan()
 
 class Span:
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "_ts", "_t0")
+                 "_ts", "_t0", "_event")
 
     def __init__(self, name: str, trace_id: str, parent_id, attrs: dict):
         self.name = name
@@ -150,9 +215,12 @@ class Span:
         self.span_id = _new_span_id()
         self.parent_id = parent_id
         self.attrs = attrs
+        self._event = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self._event is not None:
+            self._event.set_metadata(**_stats(attrs))
         return self
 
     def __enter__(self):
@@ -160,12 +228,17 @@ class Span:
         if stack is None:
             stack = _tls.stack = []
         stack.append(self)
+        ann = _profiler_annotation()
+        if ann is not None:
+            self._event = _profiler_event(ann, self.name, self.attrs)
         self._ts = _now_us()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = (time.perf_counter_ns() - self._t0) // 1000
+        if self._event is not None:
+            self._event.__exit__(*exc)
         _tls.stack.pop()
         _emit({"ph": "X", "name": self.name, "tid": self.trace_id,
                "sid": self.span_id, "psid": self.parent_id,
@@ -189,14 +262,19 @@ def current():
 
 
 def span(name: str, parent=None, **attrs):
-    """Context manager for one span; a no-op singleton when tracing is off.
+    """Context manager for one span; a no-op singleton when tracing is off
+    and no JAX profiler session records.
 
     ``parent`` is an explicit ``(trace_id, span_id)`` (cross-thread /
     cross-process); otherwise the thread's current span is the parent and
-    a parentless span starts a fresh trace.
+    a parentless span starts a fresh trace.  ``set(**attrs)`` on the span
+    adds attributes known only once its work is done.
     """
     if _ENV.get(_KEY) != _ON:         # enabled(), inlined: §12.4 hot path
-        return _NULL
+        ann = _annotation or _find_annotation()   # _profiler_annotation()
+        if ann is None or not ann.is_enabled():
+            return _NULL
+        return _ProfilerSpan(ann, name, attrs)
     if parent is None:
         parent = current()
     if parent is None:

@@ -482,17 +482,20 @@ class AnnServingEngine:
         for q in self._validate_queries(queries):
             self._pending.append(q)
 
+    def _pad(self, chunk: np.ndarray) -> np.ndarray:
+        """Pad ``chunk`` with zero rows to its bucket's compiled shape."""
+        bucket = self.bucket_for(chunk.shape[0])
+        if chunk.shape[0] < bucket:
+            pad = np.zeros((bucket - chunk.shape[0], self._dim), np.int32)
+            chunk = np.concatenate([chunk, pad])
+        return chunk
+
     def _next_batch(self) -> Optional[Tuple[np.ndarray, int]]:
         if not self._pending:
             return None
         take = self._pending[:self.serve_cfg.batch_size]
         self._pending = self._pending[len(take):]
-        batch = np.stack(take)
-        bucket = self.bucket_for(len(take))
-        if batch.shape[0] < bucket:  # pad to the bucket's compiled shape
-            pad = np.zeros((bucket - batch.shape[0], self._dim), np.int32)
-            batch = np.concatenate([batch, pad])
-        return batch, len(take)
+        return self._pad(np.stack(take)), len(take)
 
     def _run_batch(self, batch: np.ndarray, n_real: int,
                    ) -> Tuple[np.ndarray, np.ndarray]:
@@ -500,22 +503,34 @@ class AnnServingEngine:
 
         Single place for the warm/cold bookkeeping, latency stats, and the
         hedge-deadline check — ``drain`` and the cluster replica seam
-        (``run_padded``) both land here, so their metrics agree.
+        (``run_padded``) both land here, so their metrics agree.  Its spans
+        (DESIGN.md §12.2) split the batch at the layer boundaries: the
+        index's phases, the wait for the result, its copy to the host and
+        the engine's bookkeeping.
         """
-        sig = self._index_signature()
-        key = (batch.shape[0], sig)
-        if key not in self._warm:
-            self.stats["bucket_cold_hits"] += 1
-            self._warm.add(key)
         used = ()
         obs_trace.capture_begin()
-        t0 = time.perf_counter()
         with obs_trace.span("engine_batch", bucket=int(batch.shape[0]),
                             n_real=int(n_real)):
+            sig = self._index_signature()
+            key = (batch.shape[0], sig)
+            if key not in self._warm:
+                self.stats["bucket_cold_hits"] += 1
+                self._warm.add(key)
+            t0 = time.perf_counter()
             if self.serve_cfg.compact_probe:
                 d, i, used = self.index.query_compact(
                     jnp.asarray(batch), floor=self.serve_cfg.cand_bucket_min,
                     overflow=self.serve_cfg.cand_overflow, stats=self.stats)
+            else:
+                d, i = self.index.query(jnp.asarray(batch))
+            with obs_trace.span("engine.result_wait"):
+                d.block_until_ready()
+            ms = (time.perf_counter() - t0) * 1e3
+            with obs_trace.span("engine.result_fetch"):
+                dists = np.asarray(d)  # repro: allow[r1-host-sync] batch-boundary result conversion after block_until_ready
+                gids = np.asarray(i)  # repro: allow[r1-host-sync] batch-boundary result conversion after block_until_ready
+            with obs_trace.span("engine.record"):
                 for seg_key in used:
                     self.stats["cand_buckets"][seg_key[1]] += 1
                     ck = (batch.shape[0], sig) + seg_key
@@ -524,25 +539,23 @@ class AnnServingEngine:
                         # the honest recompile counter benchmarks assert on
                         self.stats["bucket_cold_hits"] += 1
                         self._warm.add(ck)
-            else:
-                d, i = self.index.query(jnp.asarray(batch))
-            d.block_until_ready()
-        ms = (time.perf_counter() - t0) * 1e3
-        if ms > self.serve_cfg.hedge_ms:
-            # hedge deadline missed: recorded here; the cluster router
-            # additionally re-issues the batch to a peer replica (§7).
-            self.stats["hedges"] += 1
-        self.stats["batches"] += 1
-        self.stats["queries"] += n_real
-        self.stats["total_ms"] += ms
-        self._lat.record_ms(ms)
-        entry = {"bucket": int(batch.shape[0]), "n_real": int(n_real),
-                 "rungs": [list(u) for u in used]}
-        if ms > self.flight.slow_ms:
-            # slow-path only: stamp the exemplar with a result preview
-            entry["preview_d"] = np.asarray(d[:1]).tolist()  # repro: allow[r1-host-sync] flight-recorder slow-exemplar capture — batch-boundary read after block_until_ready, slow path only (DESIGN.md §12)
+                if ms > self.serve_cfg.hedge_ms:
+                    # hedge deadline missed: recorded here; the cluster
+                    # router additionally re-issues the batch to a peer
+                    # replica (§7).
+                    self.stats["hedges"] += 1
+                self.stats["batches"] += 1
+                self.stats["queries"] += n_real
+                self.stats["total_ms"] += ms
+                self._lat.record_ms(ms)
+                entry = {"bucket": int(batch.shape[0]), "n_real": int(n_real),
+                         "rungs": [list(u) for u in used]}
+                if ms > self.flight.slow_ms:
+                    # slow-path only: stamp the exemplar with a result preview
+                    entry["preview_d"] = dists[:1].tolist()
+        # after the root span closes, so the exemplar holds the whole tree
         self.flight.record(ms, entry, spans=obs_trace.capture_end())
-        return np.asarray(d), np.asarray(i)  # repro: allow[r1-host-sync] batch-boundary result conversion after block_until_ready
+        return dists, gids
 
     def run_padded(self, batch: np.ndarray, n_real: int,
                    ) -> Tuple[np.ndarray, np.ndarray]:
@@ -564,20 +577,18 @@ class AnnServingEngine:
         bucket, and returns unpadded ``(Q, k)`` dists/gids.  The single-node
         mirror the cluster consistency oracle compares against.
         """
-        q = self._validate_queries(queries)
-        if q.shape[0] == 0:
+        bs = self.serve_cfg.batch_size
+        with obs_trace.span("engine.prepare"):
+            q = self._validate_queries(queries)
+            if q.shape[0] and self.serve_cfg.warm_buckets:
+                self.warmup()
+            chunks = [(self._pad(q[lo:lo + bs]), min(bs, q.shape[0] - lo))
+                      for lo in range(0, q.shape[0], bs)]
+        if not chunks:
             return (np.zeros((0, self.cfg.k), np.int32),
                     np.zeros((0, self.cfg.k), np.int32))
-        if self.serve_cfg.warm_buckets:
-            self.warmup()
         out_d, out_i = [], []
-        for lo in range(0, q.shape[0], self.serve_cfg.batch_size):
-            chunk = q[lo: lo + self.serve_cfg.batch_size]
-            n = chunk.shape[0]
-            bucket = self.bucket_for(n)
-            if n < bucket:
-                pad = np.zeros((bucket - n, self._dim), np.int32)
-                chunk = np.concatenate([chunk, pad])
+        for chunk, n in chunks:
             d, i = self._run_batch(chunk, n)
             out_d.append(d[:n])
             out_i.append(i[:n])

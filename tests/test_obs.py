@@ -213,6 +213,80 @@ def test_wire_context_and_capture(monkeypatch, tmp_path):
     json.dumps({"trace": wc})           # meta-safe: scalars only
 
 
+def _profiled_events(log_dir, body):
+    """Run ``body`` under a JAX profiler session; return the host events
+    named ``repro.*`` in its trace as ``{name: {stat: value}}``."""
+    import glob
+    import jax
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    events = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(obs_trace.PROFILER_PREFIX):
+                    events[ev.name] = dict(ev.stats)
+    return events
+
+
+def test_span_writes_a_profiler_event_while_a_session_records(
+        monkeypatch, tmp_path):
+    """REPRO_TRACE off: only the profiler's trace gets the span, with its
+    scalar attributes, those given and those set later, as stats."""
+    monkeypatch.delenv("REPRO_TRACE", raising=False)
+    monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path / "jsonl"))
+
+    def body():
+        with obs_trace.span("rung_pick", segment=4096, c_cap=None,
+                            policy="escalate", extra=[1]) as sp:
+            assert sp is not obs_trace.span("other")
+            sp.set(rung=8192, max_count=5000)
+
+    events = _profiled_events(tmp_path / "prof", body)
+    assert events == {"repro.rung_pick": {
+        "segment": 4096, "policy": "escalate", "rung": 8192,
+        "max_count": 5000}}
+    assert not (tmp_path / "jsonl").exists()        # no JSONL sink
+
+
+def test_span_writes_both_sinks_when_traced_and_profiled(monkeypatch,
+                                                         tmp_path):
+    monkeypatch.setenv("REPRO_TRACE", "1")
+    monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path / "jsonl"))
+
+    def body():
+        with obs_trace.span("engine_batch", bucket=64):
+            with obs_trace.span("rung_pick") as sp:
+                sp.set(rung=8192)
+
+    events = _profiled_events(tmp_path / "prof", body)
+    assert events == {"repro.engine_batch": {"bucket": 64},
+                      "repro.rung_pick": {"rung": 8192}}
+    obs_trace.flush()
+    spans = load_spans(str(tmp_path / "jsonl"))
+    assert {r["name"]: r["args"] for r in spans} == {
+        "engine_batch": {"bucket": 64}, "rung_pick": {"rung": 8192}}
+    assert check_spans(spans)["ok"]
+
+
+def test_span_writes_no_profiler_event_without_a_session(monkeypatch,
+                                                         tmp_path):
+    """With jax loaded but no session recording, span() is the shared
+    no-op, and a session started afterwards holds none of its spans."""
+    import jax  # noqa: F401  (the profiler check looks for it)
+    monkeypatch.delenv("REPRO_TRACE", raising=False)
+    sp = obs_trace.span("phase_a", segment=1)
+    assert sp is obs_trace.span("phase_b_rerank")
+    with sp:
+        sp.set(rung=1)
+    assert _profiled_events(tmp_path / "prof", lambda: None) == {}
+
+
 def test_check_spans_rejects_bad_records():
     assert not check_spans([])["ok"]
     bad = [{"ph": "X", "name": "a"}]
