@@ -25,10 +25,16 @@ Two executors, **bit-identical** to each other and to ``ref.fused_probe``
   are clamped to ``cap`` and prefix-summed, and the compaction gather maps
   every output slot back to its (table, probe, offset) via a second
   in-kernel bisection over the prefix sums.
-* ``fused_probe_xla`` — the XLA executor for non-TPU backends: the same
-  algorithm expressed as ``searchsorted`` + ``cumsum`` + one vectorized
-  slot->segment search; the only HBM intermediates are ``(Q, L*P)`` count
-  rows (already ~C× smaller than the staged slab) and the compact output.
+* ``fused_probe_xla`` — the XLA executor, which every backend runs
+  (``kernels/ops.executors``): ``searchsorted`` for the extents, then a
+  dense slot->bucket map in place of the kernel's second bisection.  Each
+  bucket's base delta (its flat read offset minus the previous bucket's)
+  is scatter-added at the bucket's start slot, and a prefix sum over the
+  slots turns those into every slot's flat index into ``sorted_ids``; one
+  gather then reads the ids.  The HBM intermediates are ``(Q, L*P)``
+  count rows (already ~C× smaller than the staged slab) and two
+  ``(Q, cbucket)`` slabs.  The parity tests keep it bit-identical to the
+  bisecting Pallas kernel.
 
 Output contract:
 
@@ -280,6 +286,17 @@ def compact_gather_xla(sorted_ids: jax.Array, lo: jax.Array,
     two-level overflow rung applies a tighter per-bucket cap without
     re-running phase A.  Returns (ids (Q, cbucket) int32 sentinel n,
     counts (Q,) — totals under THIS cap).
+
+    Slot j of bucket s reads ``sorted_ids`` at the flat index
+    ``base[s] + j``, with ``base[s] = lo[s] - start[s] + (s // p) * n``
+    and ``start`` the exclusive prefix of the clamped counts.  The map
+    from slots to buckets is built densely: the deltas ``base[s] -
+    base[s-1]`` are scatter-added at the starts into a zero
+    ``(Q, cbucket)`` slab and prefix-summed over the slots, so each slot
+    holds the base of the last bucket starting at or before it — its
+    owner.  O(Q*L*P + Q*cbucket), against the Pallas kernel's per-slot
+    bisection over the prefix sums (same result, pinned by the parity
+    tests).
     """
     l, n = sorted_ids.shape
     q, lp = lo.shape
@@ -288,18 +305,20 @@ def compact_gather_xla(sorted_ids: jax.Array, lo: jax.Array,
     cnt = jnp.minimum(occ, cap).astype(jnp.int32)
     csum = jnp.cumsum(cnt, axis=-1).astype(jnp.int32)   # inclusive prefix
     total = csum[:, -1]
-    start = jnp.pad(csum, ((0, 0), (1, 0)))[:, :lp]     # exclusive prefix
+    start = csum - cnt                                  # exclusive prefix
 
+    # An empty bucket shares its start with the next one, so coinciding
+    # starts telescope; starts past cbucket own no slot and drop out.
+    table = jnp.arange(lp, dtype=jnp.int32) // p
+    base = lo - start + table[None, :] * n
+    delta = jnp.diff(base, axis=-1, prepend=0)
+    rows = jnp.arange(q, dtype=jnp.int32)[:, None]
+    marks = jnp.zeros((q, cbucket), jnp.int32).at[rows, start].add(
+        delta, mode="drop")
     slot = jnp.arange(cbucket, dtype=jnp.int32)
-    seg = jax.vmap(
-        lambda cs: jnp.searchsorted(cs, slot, side="right",
-                                    method="scan_unrolled"))(csum)
-    seg = jnp.minimum(seg, lp - 1).astype(jnp.int32)
+    flat = jnp.cumsum(marks, axis=-1, dtype=jnp.int32) + slot[None, :]
     valid = slot[None, :] < total[:, None]
-    pos = (jnp.take_along_axis(lo, seg, axis=-1)
-           + slot[None, :] - jnp.take_along_axis(start, seg, axis=-1))
-    flat = (seg // p) * n + jnp.clip(pos, 0, n - 1)
-    ids = jnp.take(sorted_ids.reshape(-1), flat)
+    ids = jnp.take(sorted_ids.reshape(-1), jnp.clip(flat, 0, l * n - 1))
     return jnp.where(valid, ids, n), total
 
 

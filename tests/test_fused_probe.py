@@ -14,6 +14,8 @@ Three layers of pinning:
      (batch-bucket x candidate-bucket) warmup grid.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +30,8 @@ from repro.core.segments import SegmentedIndex
 from repro.data import ann_synthetic as ds
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.fused_probe import fused_probe_pallas, fused_probe_xla
+from repro.kernels.fused_probe import (compact_gather_xla, fused_probe_pallas,
+                                       fused_probe_xla)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -184,6 +187,83 @@ def test_two_level_cap_matches_oracle_prefix(data):
     want_ids, want_cnt = np_fused_probe(keys, ids, pk, c_cap, cbucket)
     np.testing.assert_array_equal(np.asarray(got_ids), want_ids)
     np.testing.assert_array_equal(np.asarray(got_cnt), want_cnt)
+
+
+def _miss_mask(miss, q, lp):
+    """Which (query, probe) buckets come out empty: a run of them at a
+    row's head, middle or tail (such a run shares one start), or all of
+    query 0's."""
+    mask = np.zeros((q, lp), bool)
+    run = max(2, lp // 3)
+    if miss == "head":
+        mask[:, :run] = True
+    elif miss == "middle":
+        mask[:, (lp - run) // 2:(lp - run) // 2 + run] = True
+    elif miss == "tail":
+        mask[:, -run:] = True
+    elif miss == "row":
+        mask[0] = True
+    return mask
+
+
+@pytest.mark.parametrize("l,n,p,q,cap,c_cap,cbucket,miss", [
+    (3, 60, 5, 4, 6, 6, 256, "head"),
+    (3, 60, 5, 4, 6, 6, 256, "middle"),
+    (3, 60, 5, 4, 6, 6, 256, "tail"),
+    (2, 80, 6, 3, 8, 8, 5, "none"),        # cbucket below L*P
+    (2, 80, 6, 3, 8, 8, 20, "middle"),     # cbucket below the total
+    (3, 60, 5, 4, 8, 2, 256, "tail"),      # c_cap below cap
+    (3, 60, 5, 4, 6, 3, 9, "head"),        # both truncations at once
+    (3, 60, 5, 4, 6, 6, 64, "row"),        # an all-empty query row
+    (2, 1, 4, 3, 4, 4, 16, "middle"),      # n = 1
+], ids=["empty-head", "empty-middle", "empty-tail", "cbucket-below-lp",
+        "cbucket-below-total", "c_cap-below-cap", "both-truncate",
+        "empty-row", "n1"])
+def test_extents_gather_matches_oracle(l, n, p, q, cap, c_cap, cbucket,
+                                       miss):
+    """The gather from phase-A extents maps every slot to its bucket the
+    way the oracle appends them: runs of empty buckets sharing a start,
+    a cbucket that truncates, a per-bucket ``c_cap`` under the extents'
+    ``cap``."""
+    rng = np.random.default_rng([l, n, p, q, cap, c_cap, cbucket, len(miss)])
+    keys = np.sort(2 * rng.integers(0, max(1, n // 4) + 1, (l, n)),
+                   axis=-1).astype(np.uint32)                    # even keys
+    ids = np.stack([rng.permutation(n) for _ in range(l)]).astype(np.int32)
+    hits = keys[np.arange(l)[None, :, None],
+                rng.integers(0, n, (q, l, p))]
+    # a miss is an odd key (falls between runs) or one past every key
+    misses = np.where(rng.random((q, l, p)) < 0.5,
+                      2 * rng.integers(0, n // 4 + 1, (q, l, p)) + 1,
+                      2 * n + 7).astype(np.uint32)
+    empty = _miss_mask(miss, q, l * p).reshape(q, l, p)
+    pk = np.where(empty, misses, hits).astype(np.uint32)
+    keys_j, ids_j, pk_j = map(jnp.asarray, (keys, ids, pk))
+    lo, occ, _ = kops.probe_extents(keys_j, pk_j, cap)
+    got_ids, got_cnt = kops.fused_probe(keys_j, ids_j, pk_j, c_cap, cbucket,
+                                        extents=(lo, occ))
+    want_ids, want_cnt = np_fused_probe(keys, ids, pk, c_cap, cbucket)
+    np.testing.assert_array_equal(np.asarray(got_ids), want_ids)
+    np.testing.assert_array_equal(np.asarray(got_cnt), want_cnt)
+    if miss == "row":
+        assert want_cnt[0] == 0 and (want_ids[0] == n).all()
+    if cbucket < l * p:
+        assert want_cnt.max() > cbucket         # the case truncates
+
+
+def test_compact_gather_one_slot_gather_at_serving_shape():
+    """At the serving shape (64 queries, 8 x 801 probes, the 131,072 rung,
+    1M rows) the compiled gather holds one gather over the slots: the
+    ``sorted_ids`` take.  A per-slot search over the probe extents would
+    add one per step."""
+    q, l, p, n, cbucket = 64, 8, 801, 1_000_000, 131072
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    hlo = compact_gather_xla.lower(
+        spec(l, n), spec(q, l * p), spec(q, l * p), p=p, cbucket=cbucket,
+        cap=128).compile().as_text()
+    slot_gathers = [
+        dims for dims in re.findall(r"= s32\[([0-9,]+)\]\S* gather\(", hlo)
+        if math.prod(int(d) for d in dims.split(",")) == q * cbucket]
+    assert len(slot_gathers) == 1, slot_gathers
 
 
 def test_occ_histogram_and_quantile():
