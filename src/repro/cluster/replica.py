@@ -50,6 +50,7 @@ from repro.analysis import racecheck
 from repro.ckpt import CheckpointManager
 from repro.core.index import IndexConfig, build_index
 from repro.core.segments import SegmentedIndex
+from repro.kernels.rows import widen_rows
 from repro.serve.engine import AnnServingEngine, ServeConfig
 
 from .concurrency import under_quiesce
@@ -205,7 +206,7 @@ class ShardReplica:
         """
         try:
             state, gids, next_gid = self.engine.checkpoint_payload()
-            return (np.asarray(state.dataset, np.int32),
+            return (np.asarray(widen_rows(state.dataset)),
                     np.asarray(gids, np.int32), int(next_gid))
         except RuntimeError:
             return (np.zeros((0, self.engine.index.dim), np.int32),

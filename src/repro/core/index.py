@@ -28,6 +28,7 @@ from . import hashes as hashes_lib
 from . import multiprobe as mp_lib
 from . import pipeline as pipe
 from .pipeline import l1_distance_chunked  # re-export (legacy import path)
+from repro.kernels.rows import STORED_DTYPES, UINT8_MAX_UNIVERSE, store_rows
 
 __all__ = ["IndexConfig", "IndexState", "build_index", "query_index",
            "probe_index", "finish_index", "query_index_compact",
@@ -51,8 +52,21 @@ class IndexConfig:
     probe_impl: str = "fused"    # 'fused' (lookup+gather kernel, compactable
                                  # slab) | 'staged' (legacy two-stage pair)
     k: int = 50                  # neighbors returned
-    dataset_dtype: str = "int32" # 'int16' halves rerank-gather bytes when
-                                 # universe < 32768 (EXPERIMENTS.md §Perf C1)
+    dataset_dtype: str = "int32" # stored rows (``kernels.rows``): 'int16'
+                                 # halves the rerank gather's bytes when
+                                 # universe < 32768; 'uint8' stores x // 2
+                                 # (quarter the bytes) when universe <= 510
+
+    def __post_init__(self):
+        if self.dataset_dtype not in STORED_DTYPES:
+            raise ValueError(f"dataset_dtype must be one of {STORED_DTYPES}, "
+                             f"got {self.dataset_dtype!r}")
+        if (self.dataset_dtype == "uint8"
+                and self.universe > UINT8_MAX_UNIVERSE):
+            raise ValueError(
+                f"dataset_dtype 'uint8' stores x // 2 of even coordinates "
+                f"x <= {UINT8_MAX_UNIVERSE}; universe {self.universe} does "
+                f"not fit")
 
     @property
     def probes_per_table(self) -> int:
@@ -67,7 +81,9 @@ class IndexState:
     params      : LshParams (walks/projections, offsets, key mixers)
     sorted_keys : (L, n) uint32   mixed bucket keys, ascending per table
     sorted_ids  : (L, n) int32    local row ids aligned with sorted_keys
-    dataset     : (n, m) int32    the shard's points (rerank source)
+    dataset     : (n, m)          the shard's points in their stored dtype
+                  (``cfg.dataset_dtype``; uint8 holds x // 2), the rerank
+                  source; readers widen them with ``kernels.rows.widen_rows``
     template    : (T+1, 2M) int8  universal probing template (row 0 = epicenter)
     row_offset  : ()  int32       global id of local row 0 (sharding)
     occ_from    : (L, n) int32    equal-key run length starting at each
@@ -126,6 +142,44 @@ def make_params(cfg: IndexConfig, key: jax.Array, dim: int) -> hashes_lib.LshPar
     raise ValueError(cfg.family)
 
 
+# Rows hashed per step of the build's loop: bounds the (rows, L, M) raw
+# hashes and bucket vectors (about 1.4 KB a row at L = 8, M = 22) while the
+# (L, n) keys fill in place; tests/test_chip_compile.py compiles the build
+# at n = 10M for a v5e under its HBM.
+BUILD_BLOCK_ROWS = 1 << 19
+
+
+def _row_keys(cfg: IndexConfig, params: hashes_lib.LshParams,
+              rows: jax.Array) -> jax.Array:
+    """(b, m) rows -> (L, b) uint32 mixed bucket keys."""
+    f = hashes_lib.raw_hash(params, rows, impl=cfg.hash_impl)       # (b, L, M)
+    bucket, _ = hashes_lib.bucket_and_offsets(params, f)
+    return hashes_lib.mix_keys(params, bucket).T
+
+
+def _bucket_keys(cfg: IndexConfig, params: hashes_lib.LshParams,
+                 dataset: jax.Array, block_rows: int) -> jax.Array:
+    """(L, n) uint32 keys of every row, ``block_rows`` rows at a time.
+
+    The last block is clamped to the end of the rows, so where
+    ``block_rows`` does not divide n it hashes some rows twice and writes
+    the same keys over them: each row's keys depend on that row alone.
+    """
+    n = dataset.shape[0]
+    if n <= block_rows:
+        return _row_keys(cfg, params, dataset)
+
+    def block(b, keys):
+        start = jnp.minimum(b * block_rows, n - block_rows)
+        rows = jax.lax.dynamic_slice_in_dim(dataset, start, block_rows)
+        return jax.lax.dynamic_update_slice_in_dim(
+            keys, _row_keys(cfg, params, rows), start, axis=1)
+
+    return jax.lax.fori_loop(
+        0, -(-n // block_rows), block,
+        jnp.zeros((cfg.num_tables, n), jnp.uint32))
+
+
 def build_index(
     cfg: IndexConfig,
     key: jax.Array,
@@ -136,29 +190,43 @@ def build_index(
 ) -> IndexState:
     """Build the index over one dataset shard.  Collective-free.
 
-    ``params`` may be passed in so that all shards share identical hash
-    functions (required for distributed correctness); if None they are
-    generated from ``key`` (fine for single-shard use since the same key
-    yields the same params on every shard).  ``template`` likewise may be
-    passed to reuse the (cfg-only-dependent) probing template — the
-    segmented index rebuilds small segments often and the host-side
-    template construction is not free.
+    ``dataset`` holds the coordinates themselves (int32 or int16), never
+    the stored form; the state keeps them as ``cfg.dataset_dtype``
+    (``kernels.rows.store_rows``).  ``params`` may be passed in so that all
+    shards share identical hash functions (required for distributed
+    correctness); if None they are generated from ``key`` (fine for
+    single-shard use since the same key yields the same params on every
+    shard).  ``template`` likewise may be passed to reuse the
+    (cfg-only-dependent) probing template — the segmented index rebuilds
+    small segments often and the host-side template construction is not
+    free.  The rows are hashed ``BUILD_BLOCK_ROWS`` at a time (any block
+    size gives the same index); the sort, run lengths and histogram then
+    run over the whole (L, n) keys.
     """
     n, dim = dataset.shape
+    if dataset.dtype == jnp.uint8:
+        raise ValueError("build_index takes coordinates, not uint8 stored "
+                         "rows; widen them first (kernels.rows.widen_rows)")
     if params is None:
         params = make_params(cfg, key, dim)
-    f = hashes_lib.raw_hash(params, dataset, impl=cfg.hash_impl)     # (n, L, M)
-    if cfg.dataset_dtype != str(dataset.dtype):
-        dataset = dataset.astype(jnp.dtype(cfg.dataset_dtype))
-    bucket, _ = hashes_lib.bucket_and_offsets(params, f)
-    keys = hashes_lib.mix_keys(params, bucket)                       # (n, L)
-    keys_t = keys.T                                                  # (L, n)
-    order = jnp.argsort(keys_t, axis=-1)
-    sorted_keys = jnp.take_along_axis(keys_t, order, axis=-1)
+    # The scopes name the build's device ops in a profiler trace.
+    with jax.named_scope("build_rows"):
+        keys_t = _bucket_keys(cfg, params, dataset, BUILD_BLOCK_ROWS)
+    with jax.named_scope("build_sort"):
+        # a stable sort of (key, row) pairs: argsort's order, and the keys
+        # come out sorted beside it with no gather
+        sorted_keys, order = jax.lax.sort(
+            (keys_t, jax.lax.broadcasted_iota(jnp.int32, keys_t.shape, 1)),
+            dimension=1, num_keys=1, is_stable=True)
     sorted_ids = order.astype(jnp.int32)
     if template is None:
         template = jnp.asarray(make_template(cfg))
-    occ_from = _run_lengths(sorted_keys)
+    with jax.named_scope("run_lengths"):
+        occ_from = _run_lengths(sorted_keys)
+    with jax.named_scope("occ_hist"):
+        occ_hist = _occ_histogram(sorted_keys, occ_from)
+    if cfg.dataset_dtype != str(dataset.dtype):
+        dataset = store_rows(dataset, cfg.dataset_dtype)
     return IndexState(
         params=params,
         sorted_keys=sorted_keys,
@@ -167,21 +235,30 @@ def build_index(
         template=template,
         row_offset=jnp.asarray(row_offset, jnp.int32),
         occ_from=occ_from,
-        occ_hist=_occ_histogram(sorted_keys, occ_from),
+        occ_hist=occ_hist,
     )
 
 
 def _run_lengths(sorted_keys: jax.Array) -> jax.Array:
     """(L, n) equal-key run length starting at each position (§8).
 
-    One n-target search per table at build time buys the query path out of
-    every ``side='right'`` search forever after.
+    Each position's run ends at the next run start after it (or n): one
+    reverse running minimum over the run starts' positions, computed once
+    at build time, buys the query path out of every ``side='right'``
+    search forever after.
     """
-    n = sorted_keys.shape[1]
-    run_end = jax.vmap(
-        lambda sk: jnp.searchsorted(sk, sk, side="right"))(sorted_keys)
-    return (run_end - jnp.arange(n, dtype=run_end.dtype)[None, :]
-            ).astype(jnp.int32)
+    l, n = sorted_keys.shape
+    pos = jnp.arange(n, dtype=jnp.int32)
+    if n == 0:
+        return jnp.zeros((l, 0), jnp.int32)
+    # position j + 1 where a run starts there, else n: its running minimum
+    # from the right at j is the end of the run holding j
+    next_start = jnp.where(sorted_keys[:, 1:] != sorted_keys[:, :-1],
+                           pos[None, 1:], n)
+    next_start = jnp.concatenate(
+        [next_start, jnp.full((l, 1), n, jnp.int32)], axis=1)
+    run_end = jax.lax.cummin(next_start, axis=1, reverse=True)
+    return run_end - pos[None, :]
 
 
 OCC_HIST_BINS = 32  # bin b: occupancy in (2^(b-1), 2^b]; bin 31 also > 2^30
@@ -202,16 +279,15 @@ def _occ_histogram(sorted_keys: jax.Array, occ_from: jax.Array) -> jax.Array:
     is_start = jnp.concatenate(
         [jnp.ones((l, 1), bool),
          sorted_keys[:, 1:] != sorted_keys[:, :-1]], axis=1)
-    # ceil-log2 bin of each run length; int32-safe edges up to 2^30 (a run
-    # longer than that lands in the top bin anyway).
-    edges = jnp.asarray(2 ** np.arange(31, dtype=np.int64), jnp.int32)
-    bins = jnp.searchsorted(edges, occ_from, side="left")
+    # ceil-log2 bin of each run length r >= 1, the bit length of r - 1 (a
+    # run longer than 2^30 lands in the top bin anyway)
+    bins = 32 - jax.lax.clz(occ_from - 1)
     bins = jnp.minimum(bins, OCC_HIST_BINS - 1)
-    # non-starts go to a spill column that is sliced off
+    # non-starts go to a bin that is never counted
     bins = jnp.where(is_start, bins, OCC_HIST_BINS)
-    hist = (bins[:, :, None]
-            == jnp.arange(OCC_HIST_BINS, dtype=bins.dtype)).sum(axis=1)
-    return hist.astype(jnp.int32)
+    # one (L,) count per bin, so no (L, n, bins) one-hot exists
+    return jnp.stack([(bins == b).sum(axis=1, dtype=jnp.int32)
+                      for b in range(OCC_HIST_BINS)], axis=1)
 
 
 # --------------------------------------------------------------------------

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
+from repro.kernels.rows import widen_rows
 
 from . import hashes as hashes_lib
 from . import multiprobe as mp_lib
@@ -467,9 +468,10 @@ def l1_distance_chunked(
 ) -> Tuple[jax.Array, jax.Array]:
     """Legacy exact L1 rerank: chunked scan with a ``lax.top_k`` running best.
 
-    dataset (n, m) int; queries (Q, m) int; ids (Q, Ctot) int32 with sentinel
-    n marking invalid, **deduplicated** (duplicates would each take a top-k
-    slot here — feed it ``stage_dedup`` output).  Returns (dists (Q,k) int32,
+    dataset (n, m) stored rows (``kernels.rows``); queries (Q, m) int; ids
+    (Q, Ctot) int32 with sentinel n marking invalid, **deduplicated**
+    (duplicates would each take a top-k slot here — feed it
+    ``stage_dedup`` output).  Returns (dists (Q,k) int32,
     ids (Q,k) int32) sorted ascending; invalid entries have dist =
     INT32_MAX/2 and id = -1.
 
@@ -490,9 +492,9 @@ def l1_distance_chunked(
         best_d, best_i = carry                                      # (Q,k)
         sl = jnp.clip(step_ids, 0, n - 1)                           # (Q,c)
         rows = dataset[sl]                                          # (Q,c,m)
-        # HBM gather stays at dataset dtype (int16 under §Perf C1);
-        # the |diff| accumulation is widened to int32 in registers.
-        diff = rows.astype(jnp.int32) - queries[:, None, :].astype(jnp.int32)
+        # HBM gather stays at the stored dtype (int16 or uint8); the rows
+        # are widened to int32 coordinates in registers.
+        diff = widen_rows(rows) - queries[:, None, :].astype(jnp.int32)
         d = jnp.abs(diff).sum(axis=-1).astype(jnp.int32)
         d = jnp.where(step_ids >= n, big, d)
         cd = jnp.concatenate([best_d, d], axis=-1)

@@ -42,6 +42,7 @@ from . import hashes as hashes_lib
 from . import pipeline as pipe
 from .index import (IndexConfig, IndexState, build_index, make_params,
                     make_template, probe_index)
+from repro.kernels.rows import widen_rows
 from repro.obs import trace as obs_trace
 
 __all__ = ["Segment", "SegmentedIndex"]
@@ -299,6 +300,16 @@ class SegmentedIndex:
         total = sum(s.size for s in self.segments) + self._delta_count
         return total - len(self._tombstones)
 
+    def device_bytes(self) -> int:
+        """Device bytes of the sealed segments' rows and tables: stored
+        rows, sorted keys and ids, run lengths and gids (the delta buffer
+        and the tombstones are host state until a query copies them)."""
+        return sum(int(a.nbytes) for seg in self.segments
+                   for a in (seg.state.dataset, seg.state.sorted_keys,
+                             seg.state.sorted_ids, seg.state.occ_from,
+                             seg.gids)
+                   if a is not None)
+
     @property
     def num_tombstones(self) -> int:
         return len(self._tombstones)
@@ -382,7 +393,7 @@ class SegmentedIndex:
         for seg in self.segments:
             if seg.fingerprint != self.fingerprint:
                 raise ValueError("segment params diverged; cannot compact")
-            parts.append(np.asarray(seg.state.dataset, np.int32))  # repro: allow[r1-host-sync] compaction materializes on host by design
+            parts.append(np.asarray(widen_rows(seg.state.dataset)))  # repro: allow[r1-host-sync] compaction materializes on host by design
             gid_parts.append(np.asarray(seg.gids))  # repro: allow[r1-host-sync] compaction materializes on host by design
         if self._delta_count:
             parts.append(self._delta_points[:self._delta_count].copy())
@@ -522,7 +533,7 @@ class SegmentedIndex:
         s = min(self.cap_sample, seg.size)
         if s > 0:
             stride = max(1, seg.size // s)
-            sample = state.dataset[::stride][:s].astype(jnp.int32)
+            sample = widen_rows(state.dataset[::stride][:s])
             _, _, occ, _ = _probe_segment(cfg, state, sample)
             totals = np.minimum(np.asarray(occ), c_norm).sum(axis=-1)  # repro: allow[r1-host-sync] seal-time occupancy sampling, once per segment
             realized = int(np.percentile(totals, 90))
@@ -628,9 +639,11 @@ class SegmentedIndex:
                     max_count, seg.ctot_cap, floor,
                     seg.ctot_norm, seg.c_norm, overflow)
                 sp.set(rung=cb, max_count=max_count, c_cap=c_cap)
+            rows = seg.state.dataset
             with obs_trace.span("phase_b_rerank", segment=int(seg.size),
                                 cbucket=int(cb),
-                                c_cap=None if c_cap is None else int(c_cap)):
+                                c_cap=None if c_cap is None else int(c_cap),
+                                row_bytes=rows.shape[1] * rows.dtype.itemsize):
                 res = _finish_segment(
                     self.cfg, cb, c_cap, seg.state, seg.gids, tomb,
                     probe_keys, lo, occ, queries)

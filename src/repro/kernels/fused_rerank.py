@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .rows import widen_rows
 from .topk_merge import bitonic_sort_rows, bitonic_topk_merge_rows
 
 __all__ = ["fused_rerank_pallas", "fused_rerank_xla", "BIG_DIST"]
@@ -107,7 +108,7 @@ def _fused_kernel(q_ref, ids_ref, data_ref, do_ref, io_ref, *,
         def m_step(u, acc):
             sub = jax.lax.dynamic_slice(data, (0, u * bm), (n_rows, bm))
             rows = jnp.take(sub, safe.reshape(-1), axis=0)
-            rows = rows.reshape(bq, bc, bm).astype(jnp.int32)
+            rows = widen_rows(rows.reshape(bq, bc, bm))
             qsub = jax.lax.dynamic_slice(qs, (0, u * bm), (bq, bm))
             return acc + jnp.abs(rows - qsub[:, None, :]).sum(-1)
 
@@ -143,8 +144,9 @@ def fused_rerank_pallas(
 ):
     """Fused gather + L1 + running-top-k.  See module docstring for contract.
 
-    dataset (n, m) int; queries (Q, m) int; ids (Q, Ctot) int32 (slots < 0 or
-    >= n are invalid; ids need NOT be deduplicated).  Returns
+    dataset (n, m) stored rows (``kernels.rows``: uint8 rows hold x // 2);
+    queries (Q, m) int; ids (Q, Ctot) int32 (slots < 0 or >= n are invalid;
+    ids need NOT be deduplicated).  Returns
     (dists (Q, k) int32, ids (Q, k) int32), lex-(dist, id) ascending.
     """
     n, m = dataset.shape
@@ -230,7 +232,9 @@ def fused_rerank_xla(
     def body(_, step_ids):
         sl = jnp.clip(step_ids, 0, n - 1)                           # (Q,c)
         rows = dataset[sl]                                          # (Q,c,m)
-        diff = rows.astype(jnp.int32) - queries[:, None, :].astype(jnp.int32)
+        with jax.named_scope("widen"):
+            rows = widen_rows(rows)
+        diff = rows - queries[:, None, :].astype(jnp.int32)
         d = jnp.abs(diff).sum(axis=-1).astype(jnp.int32)
         return None, jnp.where(step_ids >= n, big, d)
 

@@ -178,9 +178,11 @@ def lower_ann_cell(multi_pod: bool = False, n_global: int = 1 << 27,
     from repro.core import hashes as hashes_lib
     from repro.launch import dist_index as di
 
+    # uint8 rows hold x // 2, so their universe stops at 510
+    universe = 510 if dataset_dtype == "uint8" else 512
     cfg = IndexConfig(num_tables=8, num_hashes=16, width=256, num_probes=100,
-                      candidate_cap=8, universe=512, k=50, rerank_chunk=1024,
-                      dataset_dtype=dataset_dtype)
+                      candidate_cap=8, universe=universe, k=50,
+                      rerank_chunk=1024, dataset_dtype=dataset_dtype)
     mesh = make_production_mesh(multi_pod=multi_pod)
     rows = di._row_axes(mesh)
     nshards = 1
@@ -238,7 +240,8 @@ def main(argv=None):
     ap.add_argument("--ann", action="store_true", help="lower the ANN index cell")
     ap.add_argument("--merge", default="allgather",
                     choices=["allgather", "ring", "tree"])
-    ap.add_argument("--dataset-dtype", default="int32", choices=["int32", "int16"])
+    ap.add_argument("--dataset-dtype", default="int32",
+                    choices=["int32", "int16", "uint8"])
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--json", default=None)
     ap.add_argument("--no-unroll", action="store_true",

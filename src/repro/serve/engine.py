@@ -335,6 +335,7 @@ class AnnServingEngine:
             self.stats[k] = 0.0
         self.metrics.family("cand_buckets")
         self._lat = self.metrics.histogram("batch_ms")
+        self._count_index_bytes()
         # flight recorder: bounded ring of recent batches + slow exemplars
         # (a batch past the hedge deadline is by definition worth a look)
         self.flight = FlightRecorder(slow_ms=serve_cfg.hedge_ms)
@@ -432,6 +433,7 @@ class AnnServingEngine:
         gids = self.index.insert(points)
         self.stats["inserts"] += len(gids)
         self._maybe_compact()
+        self._count_index_bytes()
         return gids
 
     def delete(self, gids) -> int:
@@ -450,12 +452,18 @@ class AnnServingEngine:
         t0 = time.perf_counter()
         self.index.compact()
         self.stats["compact_ms"] += (time.perf_counter() - t0) * 1e3
+        self._count_index_bytes()
         # Compaction changes structure_signature(), so every warm bucket
         # just went cold.  Re-warm immediately: the XLA compiles land in
         # warmup_ms instead of silently inflating the next batches, and
         # bucket_cold_hits stays an honest "unplanned recompile" counter.
         if self.serve_cfg.warm_buckets:
             self.warmup()
+
+    def _count_index_bytes(self) -> None:
+        """``stats['index_bytes']``: the device bytes of the sealed
+        segments' rows and tables, after the last change to them."""
+        self.stats["index_bytes"] = self.index.device_bytes()
 
     def _maybe_compact(self) -> None:
         """Watermark-triggered compaction (DESIGN.md Sect. 3).

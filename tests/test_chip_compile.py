@@ -4,11 +4,15 @@ No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and refuses here what it would refuse on the chip (an op that
 does not lower, a program that does not fit).  Shapes are those of
 ``chip_smoke.py``: n = 1,000,000 rows of dim 128, Q = 64, L = 8, the
-smoke's probes and candidate rungs.  Code that asks ``jax.default_backend()``
+smoke's probes and candidate rungs; the build and phase B also at the
+``bigann10m-u8`` benchmark configuration's n = 10,000,000 uint8 rows.
+Code that asks ``jax.default_backend()``
 sees the CPU here, so each compile steers the executor choice to the one
 the chip makes.  All compiles stay in this one file: only the process that
 describes the topology may load the TPU library.
 """
+import dataclasses
+import json
 import os
 import sys
 
@@ -29,6 +33,25 @@ import chip_smoke as smoke  # noqa: E402
 SHAPE = smoke.SIFT1M
 CFG = smoke.index_config(SHAPE)
 WORST_RUNG = CFG.num_tables * CFG.probes_per_table * CFG.candidate_cap
+
+
+def _bigann():
+    """(shape, index config) of the ``bigann10m-u8`` benchmark cell."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "configs", "bigann10m-u8.json")
+    with open(path) as f:
+        conf = json.load(f)
+    law = conf["data"]
+    shape = dataclasses.replace(SHAPE, n=law["n"], dim=law["dim"],
+                                universe=law["universe"])
+    return shape, smoke.index_config(shape, **conf["index"])
+
+
+BIGANN, BIGANN_CFG = _bigann()
+BIGANN_WORST_RUNG = (BIGANN_CFG.num_tables * BIGANN_CFG.probes_per_table
+                     * BIGANN_CFG.candidate_cap)
+# (shape, index config): SIFT1M's int32 rows, bigann-10M's uint8 rows
+STORES = {"1M-int32": (SHAPE, CFG), "10M-uint8": (BIGANN, BIGANN_CFG)}
 TOMBSTONES = 1 << (SHAPE.deletes - 1).bit_length()
 HBM_BYTES = 16 * 2 ** 30           # one v5e chip
 EXECUTORS = kops.executors         # the real table, before any steering
@@ -83,14 +106,14 @@ def _spec_of(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _state(chip):
-    data = jax.ShapeDtypeStruct((SHAPE.n, SHAPE.dim), jnp.int32)
+def _state(chip, shape=SHAPE, cfg=CFG):
+    data = jax.ShapeDtypeStruct((shape.n, shape.dim), jnp.int32)
     return _on(chip, jax.eval_shape(
-        lambda d: build_index(CFG, jax.random.PRNGKey(0), d), data))
+        lambda d: build_index(cfg, jax.random.PRNGKey(0), d), data))
 
 
-def _queries(chip):
-    return _spec_of((SHAPE.batch, SHAPE.dim), jnp.int32, chip)
+def _queries(chip, shape=SHAPE):
+    return _spec_of((shape.batch, shape.dim), jnp.int32, chip)
 
 
 def _compile(fn, *args):
@@ -101,10 +124,14 @@ def _compile(fn, *args):
     assert used < HBM_BYTES, used
 
 
-def test_compile_build_hash_and_sort(as_chip):
-    params = make_params(CFG, jax.random.PRNGKey(0), SHAPE.dim)
-    build = jax.jit(lambda d, p: build_index(CFG, None, d, params=p))
-    _compile(build, _spec_of((SHAPE.n, SHAPE.dim), jnp.int32, as_chip),
+@pytest.mark.parametrize("store", sorted(STORES))
+def test_compile_build_hash_and_sort(as_chip, store):
+    """The build as one program, its rows hashed in blocks: at 10M rows
+    the int32 input, the index and the block's temporaries fit."""
+    shape, cfg = STORES[store]
+    params = make_params(cfg, jax.random.PRNGKey(0), shape.dim)
+    build = jax.jit(lambda d, p: build_index(cfg, None, d, params=p))
+    _compile(build, _spec_of((shape.n, shape.dim), jnp.int32, as_chip),
              _on(as_chip, params))
 
 
@@ -112,13 +139,16 @@ def test_compile_phase_a_probe_extents(as_chip):
     _compile(probe_index, CFG, _state(as_chip), _queries(as_chip))
 
 
-@pytest.mark.parametrize("rung", [smoke.CAND_BUCKET_MIN, WORST_RUNG])
-def test_compile_phase_b_gather_tombstone_rerank(as_chip, rung):
-    state, q = _state(as_chip), _queries(as_chip)
+@pytest.mark.parametrize("store, rung", [
+    ("1M-int32", smoke.CAND_BUCKET_MIN), ("1M-int32", WORST_RUNG),
+    ("10M-uint8", BIGANN_WORST_RUNG)])
+def test_compile_phase_b_gather_tombstone_rerank(as_chip, store, rung):
+    shape, cfg = STORES[store]
+    state, q = _state(as_chip, shape, cfg), _queries(as_chip, shape)
     probe_keys, lo, occ, _ = _on(as_chip, jax.eval_shape(
-        lambda s, x: probe_index(CFG, s, x), state, q))
-    _compile(_finish_segment, CFG, rung, None, state,
-             _spec_of((SHAPE.n,), jnp.int32, as_chip),
+        lambda s, x: probe_index(cfg, s, x), state, q))
+    _compile(_finish_segment, cfg, rung, None, state,
+             _spec_of((shape.n,), jnp.int32, as_chip),
              _spec_of((TOMBSTONES,), jnp.int32, as_chip),
              probe_keys, lo, occ, q)
 
