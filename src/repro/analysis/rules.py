@@ -48,7 +48,7 @@ DEVICE_FNS = {
     "stage_candidate_gather", "stage_probe_extents", "stage_probe_counts",
     "stage_fused_probe", "stage_dedup", "stage_tombstone", "stage_rerank",
     "stage_merge_pair", "stage_merge_concat",
-    "probe_candidates", "l1_distance_chunked",
+    "probe_candidates", "rerank_candidates", "l1_distance_chunked",
     "fused_probe", "fused_rerank", "topk_merge",
 }
 

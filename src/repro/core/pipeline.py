@@ -3,7 +3,7 @@
 The query flow is decomposed into pure, statically-shaped stages
 
     hash -> probe-gen -> bucket-lookup -> candidate-gather -> dedup
-         [-> tombstone] -> rerank -> merge
+         -> rerank at k + T -> gid map [-> tombstone -> first k] -> merge
 
 so that the single-shard path (``core.index.query_index``), the shard_map
 path (``launch.dist_index``), and the serving engine (``serve.engine``)
@@ -20,10 +20,19 @@ Stage contracts (Q queries, L tables, M hashes, P probes/table, C cap):
   stage_probe_counts : sorted_keys, probe_keys -> counts (Q,) valid cands
   stage_fused_probe : sorted_keys/ids, probe_keys -> ids (Q, Cb), counts (Q,)
   stage_dedup      : ids                       -> ids, duplicates -> sentinel
-  stage_tombstone  : ids, gids, tombstones     -> ids, deleted -> sentinel
   stage_rerank     : dataset, queries, ids     -> (dists, ids) (Q, k) asc
+  stage_tombstone  : (dists, gids) (Q, k + T), tombstones
+                                               -> first k live (Q, k) asc
+  rerank_candidates : rerank -> gid map -> tombstone (T = tombstone length)
   stage_merge_pair : two (Q, k) ascending lists -> one (Q, k) ascending list
   stage_merge_concat : (Q, R*k) stacked lists  -> (Q, k)
+
+Tombstones (DESIGN.md §3.1) apply after selection, not before it: the
+rerank selects the k + T lex-(dist, id)-smallest unique candidates, where T
+is the static length of the tombstone array; at most T of them are dead,
+so the first k live ones among them are exactly the k a per-candidate mask
+ahead of the rerank would leave.  The check then costs Q * (k + T) lookups
+instead of one per slot of the candidate slab.
 
 Probe dispatch (DESIGN.md §8): ``cfg.probe_impl`` selects between the fused
 lookup+gather kernel (``kernels/fused_probe``, the default) and the legacy
@@ -69,10 +78,12 @@ __all__ = [
     "stage_probe_counts",
     "stage_fused_probe",
     "stage_dedup",
-    "stage_tombstone",
     "probe_candidates",
     "rerank_handles_duplicates",
     "stage_rerank",
+    "stage_tombstone",
+    "tomb_select",
+    "rerank_candidates",
     "stage_merge_pair",
     "stage_merge_concat",
     "l1_distance_chunked",
@@ -401,25 +412,6 @@ def stage_dedup(ids: jax.Array, n: int) -> jax.Array:
     return jnp.where(dup, n, ids)
 
 
-def stage_tombstone(
-    ids: jax.Array, gids: jax.Array, tombstones: jax.Array, n: int,
-) -> jax.Array:
-    """Mask deleted points out of the candidate list (DESIGN.md Sect. 3).
-
-    ids        : (Q, Ctot) local ids with sentinel n.
-    gids       : (n,) global id of each local row.
-    tombstones : (t,) ascending int32 global ids, padded with INT32_MAX
-                 (the pad value matches no real gid, so no count is needed).
-    Applied *before* rerank so a deleted point can never occupy a top-k slot.
-    """
-    if n == 0:
-        return ids  # nothing to map: every slot already carries the sentinel
-    gid = gids[jnp.clip(ids, 0, n - 1)]
-    pos = jnp.searchsorted(tombstones, gid)
-    hit = tombstones[jnp.clip(pos, 0, tombstones.shape[0] - 1)] == gid
-    return jnp.where((ids < n) & hit, n, ids)
-
-
 def probe_candidates(
     cfg, params: hashes_lib.LshParams, template: jax.Array,
     sorted_keys: jax.Array, sorted_ids: jax.Array, n: int,
@@ -510,30 +502,96 @@ def l1_distance_chunked(
 
 def stage_rerank(
     cfg, dataset: jax.Array, queries: jax.Array, ids: jax.Array,
-    impl: Optional[str] = None,
+    impl: Optional[str] = None, k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact-rerank stage; dispatches on ``cfg.rerank_impl``.
 
     'fused' (default): the fused gather+L1+running-top-k kernel — dedups
     internally, so it accepts the raw (non-deduplicated) candidate gather.
     'scan': the legacy chunked scan + lax.top_k — requires deduplicated ids.
-    Both return identical bits (the k lex-(dist, id)-smallest unique
-    candidates, ascending; invalid -> (BIG_DIST, -1)).
+    Both return identical bits (the ``k`` (default ``cfg.k``)
+    lex-(dist, id)-smallest unique candidates, ascending; invalid ->
+    (BIG_DIST, -1)).
     """
     impl = impl or getattr(cfg, "rerank_impl", "fused")
+    k = cfg.k if k is None else k
     if dataset.shape[0] == 0:
         # No rows to rank against; both executors would gather from a
         # zero-length dataset.  Emit the all-invalid result directly.
         q = ids.shape[0]
-        return (jnp.full((q, cfg.k), BIG_DIST, jnp.int32),
-                jnp.full((q, cfg.k), -1, jnp.int32))
+        return (jnp.full((q, k), BIG_DIST, jnp.int32),
+                jnp.full((q, k), -1, jnp.int32))
     if impl == "scan":
         return l1_distance_chunked(
-            dataset, queries, ids, cfg.k, cfg.rerank_chunk)
+            dataset, queries, ids, k, cfg.rerank_chunk)
     if impl != "fused":
         raise ValueError(f"unknown rerank_impl: {impl!r}")
     return kops.fused_rerank(
-        dataset, queries, ids, cfg.k, chunk=cfg.rerank_chunk)
+        dataset, queries, ids, k, chunk=cfg.rerank_chunk)
+
+
+def stage_tombstone(
+    dists: jax.Array, gids: jax.Array, tombstones: jax.Array, k: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Drop deleted points from a selected list; keep the first k live.
+
+    dists, gids : (Q, K) ascending selection mapped to global ids, K >= k;
+                  invalid slots carry (BIG_DIST, -1).
+    tombstones  : (t,) ascending int32 global ids, padded with INT32_MAX
+                  (the pad matches no real gid, so no count is needed).
+    Returns (dists, gids) (Q, k): the live entries in their order, then
+    (BIG_DIST, -1).  Exact when the selection holds at least k live
+    candidates or all of them, which ``tomb_select`` guarantees.
+    """
+    pos = jnp.searchsorted(tombstones, gids)
+    dead = tombstones[jnp.clip(pos, 0, tombstones.shape[0] - 1)] == gids
+    live = (gids >= 0) & ~dead
+    # A stable sort on the flag moves the live entries to the front in
+    # their order; K is k + T, so this is a few lanes per query.
+    order = jnp.argsort(~live, axis=-1, stable=True)[:, :k]
+
+    def first_live(a, invalid):
+        return jnp.take_along_axis(jnp.where(live, a, invalid), order,
+                                   axis=-1)
+
+    return first_live(dists, BIG_DIST), first_live(gids, -1)
+
+
+def tomb_select(k: int, t: int, ctot: int) -> int:
+    """How many candidates the rerank selects ahead of ``stage_tombstone``.
+
+    k + t, where t is the tombstone array's static length: at most t
+    selected candidates are dead, so k live ones remain whenever the slab
+    holds them.  Never more than the slab's ``ctot`` slots (all of them
+    are then kept), and never fewer than k (the rerank pads).
+    """
+    return max(k, min(k + t, ctot))
+
+
+def rerank_candidates(
+    cfg, dataset: jax.Array, queries: jax.Array, ids: jax.Array,
+    gids: jax.Array, tombstones: jax.Array,
+) -> Tuple[jax.Array, jax.Array]:
+    """rerank at k + T -> gid map -> tombstone -> first k (DESIGN.md §3.1).
+
+    The one tail of every path that applies tombstones (``segments``:
+    ``_query_segment``, ``_finish_segment``, ``_query_delta``).  ids (Q,
+    Ctot) local candidate ids, sentinel n (deduplicated for the 'scan'
+    rerank); gids (n,) the global id of each local row.  Returns
+    (dists, gids) (Q, k), lex-(dist, local id) ascending, invalid ->
+    (BIG_DIST, -1): the same bits as masking dead candidates out of the
+    slab before a rerank at k.
+    """
+    n = dataset.shape[0]
+    sel = tomb_select(cfg.k, tombstones.shape[0], ids.shape[1])
+    with jax.named_scope("rerank"):
+        d, i = stage_rerank(cfg, dataset, queries, ids, k=sel)
+    if n == 0:  # zero-point segment: all invalid, gids is empty
+        return d[:, :cfg.k], i[:, :cfg.k]
+    with jax.named_scope("gid_map"):
+        gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, n - 1)], -1)
+    with jax.named_scope("tombstone"):
+        return stage_tombstone(d, gid, tombstones, cfg.k)
 
 
 def stage_merge_pair(

@@ -9,9 +9,10 @@ and deletes without an O(n log n) rebuild per mutation.  LSM-style layout:
   * a small mutable **delta buffer** of freshly inserted points.  It is
     unindexed; queries scan it with the exact L1 rerank stage (it is tiny
     by construction, so the scan is cheaper than hashing it per mutation);
-  * a **tombstone set** of deleted global ids, applied at the candidate
-    stage of every query (``pipeline.stage_tombstone``) so a dead point can
-    never occupy a top-k slot;
+  * a **tombstone set** of deleted global ids, checked on the k + T
+    candidates each query's rerank selects (``pipeline.rerank_candidates``,
+    T the tombstone array's length) so a dead point can never occupy a
+    top-k slot;
   * ``compact()`` merges segments + delta - tombstones back into ONE
     segment, after which a query is bit-identical (in distances) to a fresh
     ``build_index`` over the surviving points in insertion order.
@@ -95,7 +96,8 @@ def _seg_ctot_cap(cfg: IndexConfig, state: IndexState) -> int:
 @partial(jax.jit, static_argnums=0)
 def _query_segment(cfg: IndexConfig, state: IndexState, gids: jax.Array,
                    tombstones: jax.Array, queries: jax.Array):
-    """Full pipeline over one segment: probe -> tombstone -> rerank -> gid.
+    """Full pipeline over one segment: probe -> rerank at k + T -> gid map
+    -> tombstone -> first k (``pipe.rerank_candidates``).
 
     Under the default ``cfg.rerank_impl='fused'`` the candidate list is NOT
     pre-deduplicated (``probe_candidates`` skips the sorting dedup; the
@@ -108,12 +110,8 @@ def _query_segment(cfg: IndexConfig, state: IndexState, gids: jax.Array,
     ids = pipe.probe_candidates(
         cfg, state.params, state.template, state.sorted_keys,
         state.sorted_ids, n, queries)
-    ids = pipe.stage_tombstone(ids, gids, tombstones, n)
-    d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
-    if n == 0:  # zero-point segment: rerank is all-invalid, gids is empty
-        return d, i
-    gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, n - 1)], -1)
-    return d, gid
+    return pipe.rerank_candidates(cfg, state.dataset, queries, ids, gids,
+                                  tombstones)
 
 
 # Compaction phase A over one segment == the flat index's phase A (a
@@ -141,7 +139,9 @@ def _finish_segment(cfg: IndexConfig, cbucket: int, c_cap: Optional[int],
                     probe_keys: jax.Array, lo: jax.Array, occ: jax.Array,
                     queries: jax.Array):
     """Compaction phase B: compacted gather at the (static) rung
-    -> [dedup ->] tombstone -> rerank -> gid map.  Same stage order as
+    -> [dedup ->] rerank at k + T -> gid map -> tombstone -> first k.
+    The tombstones are checked on the k + T selected candidates, not on
+    every slot of the rung (``pipe.rerank_candidates``).  Same stages as
     ``_query_segment``, so results are bit-identical at any non-truncating
     ``cbucket`` with ``c_cap=None`` — only the padding lanes the rerank
     pays for shrink.  An int ``c_cap`` is the two-level truncate rung's
@@ -157,15 +157,8 @@ def _finish_segment(cfg: IndexConfig, cbucket: int, c_cap: Optional[int],
     if not pipe.rerank_handles_duplicates(cfg):
         with jax.named_scope("dedup"):
             ids = pipe.stage_dedup(ids, n)
-    with jax.named_scope("tombstone"):
-        ids = pipe.stage_tombstone(ids, gids, tombstones, n)
-    with jax.named_scope("rerank"):
-        d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
-    if n == 0:
-        return d, i
-    with jax.named_scope("gid_map"):
-        gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, n - 1)], -1)
-    return d, gid
+    return pipe.rerank_candidates(cfg, state.dataset, queries, ids, gids,
+                                  tombstones)
 
 
 @partial(jax.jit, static_argnums=0)
@@ -177,10 +170,8 @@ def _query_delta(cfg: IndexConfig, buffer: jax.Array, gids: jax.Array,
         jnp.where(jnp.arange(cap, dtype=jnp.int32) < count,
                   jnp.arange(cap, dtype=jnp.int32), cap),
         (queries.shape[0], cap))
-    ids = pipe.stage_tombstone(ids, gids, tombstones, cap)
-    d, i = pipe.stage_rerank(cfg, buffer, queries, ids)
-    gid = jnp.where(i >= 0, gids[jnp.clip(i, 0, cap - 1)], -1)
-    return d, gid
+    return pipe.rerank_candidates(cfg, buffer, queries, ids, gids,
+                                  tombstones)
 
 
 class SegmentedIndex:
@@ -643,7 +634,9 @@ class SegmentedIndex:
             with obs_trace.span("phase_b_rerank", segment=int(seg.size),
                                 cbucket=int(cb),
                                 c_cap=None if c_cap is None else int(c_cap),
-                                row_bytes=rows.shape[1] * rows.dtype.itemsize):
+                                row_bytes=rows.shape[1] * rows.dtype.itemsize,
+                                tomb_select=pipe.tomb_select(
+                                    self.cfg.k, int(tomb.shape[0]), cb)):
                 res = _finish_segment(
                     self.cfg, cb, c_cap, seg.state, seg.gids, tomb,
                     probe_keys, lo, occ, queries)

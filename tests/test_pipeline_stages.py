@@ -57,16 +57,20 @@ def test_probe_candidates_unique_on_cloned_points():
 
 
 def test_stage_tombstone_masks_deleted_gids():
-    n = 6
-    gids = jnp.asarray([10, 11, 12, 13, 14, 15], jnp.int32)
-    ids = jnp.asarray([[0, 2, 4, n, n, n]], jnp.int32)
+    # a selected (dist, gid) list of k + T = 4 entries, k = 3
+    dists = jnp.asarray([[1, 2, 3, BIG]], jnp.int32)
+    gids = jnp.asarray([[10, 12, 14, -1]], jnp.int32)
     tomb = jnp.asarray([12, np.iinfo(np.int32).max], jnp.int32)  # kill gid 12
-    out = np.asarray(pipe.stage_tombstone(ids, gids, tomb, n))[0]
-    assert out.tolist() == [0, n, 4, n, n, n]
-    # empty tombstone set (all padding) is a no-op
+    d, g = (np.asarray(a)[0] for a in
+            pipe.stage_tombstone(dists, gids, tomb, 3))
+    assert g.tolist() == [10, 14, -1]
+    assert d.tolist() == [1, 3, BIG]
+    # empty tombstone set (all padding) keeps the first k as they are
     pad = jnp.asarray([np.iinfo(np.int32).max], jnp.int32)
-    out2 = np.asarray(pipe.stage_tombstone(ids, gids, pad, n))[0]
-    assert out2.tolist() == list(np.asarray(ids)[0])
+    d2, g2 = (np.asarray(a)[0] for a in
+              pipe.stage_tombstone(dists, gids, pad, 3))
+    assert g2.tolist() == [10, 12, 14]
+    assert d2.tolist() == [1, 2, 3]
 
 
 # ---------------------------------------------------------------------------

@@ -269,3 +269,27 @@ def test_phase_b_span_carries_the_stored_row_bytes(data, monkeypatch,
     got = {r["args"]["row_bytes"] for r in spans
            if r["name"] == "phase_b_rerank"}
     assert got == {row_bytes}
+
+
+def test_phase_b_span_carries_the_tombstone_selection(data, monkeypatch,
+                                                      tmp_path):
+    """``tomb_select`` is k + T, T the tombstone array's length: 1 (the
+    pad) before any delete, 8 after five."""
+    from repro.obs import trace as obs_trace
+    monkeypatch.setenv("REPRO_TRACE", "1")
+    x, q, _ = data
+    engine = AnnServingEngine(
+        CONFIGS["lsh"], ServeConfig(batch_size=16, bucket_min=16,
+                                    warm_buckets=False, cand_bucket_min=64,
+                                    tombstone_watermark=2.0, hedge_ms=1e9),
+        dataset=jnp.asarray(x), key=KEY)
+    for dead, t in (((), 1), ((3, 5, 8, 13, 21), 8)):
+        engine.delete(np.asarray(dead, np.int64))
+        monkeypatch.setenv("REPRO_TRACE_DIR", str(tmp_path / str(t)))
+        engine.query_batch(q)
+        obs_trace.flush()
+        spans = [json.loads(line)
+                 for path in (tmp_path / str(t)).glob("*.jsonl")
+                 for line in path.read_text().splitlines()]
+        assert {r["args"]["tomb_select"] for r in spans
+                if r["name"] == "phase_b_rerank"} == {K + t}
