@@ -7,8 +7,8 @@ hardware-independent, plus wall time of the jnp reference for regression
 tracking.
 
 ``--rerank-json BENCH_rerank.json`` (default on) additionally runs the
-rerank-stage benchmark — fused (sort-free dedup + gather+L1+running-top-k)
-vs the legacy sort-dedup + chunked scan + lax.top_k vs a naive full
+rerank-stage benchmark — the served rerank (id dedup + chunked L1 + one
+selection sort) vs a sort-dedup + chunked scan + lax.top_k vs a naive full
 materialize + sort — and emits a machine-readable JSON so the perf
 trajectory is tracked from ISSUE 2 onward.  ``--smoke`` shrinks every shape
 for CPU-only CI runners.
@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import pipeline as pipe
+from repro.core import baselines as bl
 from repro.core import walks as wl
 from repro.kernels import ops, ref
 
@@ -60,12 +60,15 @@ def rerank_bench(smoke: bool = False, json_out: str = "BENCH_rerank.json"):
     ids = jnp.asarray(ids_np)
 
     @jax.jit
-    def scan_path(ds, qs, cand):   # legacy: sort-dedup + chunked scan+top_k
-        return pipe.l1_distance_chunked(
-            ds, qs, pipe.stage_dedup(cand, n), k, chunk)
+    def scan_path(ds, qs, cand):   # sort-dedup + chunked scan+top_k
+        srt = jnp.sort(cand, axis=-1)
+        dup = jnp.concatenate([jnp.zeros((q, 1), bool),
+                               srt[:, 1:] == srt[:, :-1]], axis=-1)
+        return bl.l1_distance_chunked(
+            ds, qs, jnp.where(dup, n, srt), k, chunk)
 
     @jax.jit
-    def fused_path(ds, qs, cand):  # fused kernel path (xla executor on CPU)
+    def fused_path(ds, qs, cand):  # the served rerank
         return ops.fused_rerank(ds, qs, cand, k, chunk=chunk)
 
     @jax.jit

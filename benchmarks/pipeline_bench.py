@@ -1,15 +1,13 @@
-"""Query-pipeline stage breakdown + fused/compacted front-end shootout
-(ISSUE 5 acceptance).  Emits machine-readable ``BENCH_pipeline.json``.
+"""Query-pipeline stage breakdown + candidate-rung shootout.  Emits
+machine-readable ``BENCH_pipeline.json``.
 
-Per-stage wall times for the staged pipeline (hash / probe-keys /
-lookup+gather / rerank / merge), then the head-to-head the tentpole is
-about: the legacy staged lookup+gather materializes the worst-case
-``(Q, L*P*C)`` candidate slab (mostly sentinels — the occupancy figure in
-the JSON shows how mostly), while the fused front-end runs the two-phase
-compacted path (counts -> pow-2 candidate bucket -> fused lookup+gather at
-that width, host round-trip included).  Outputs are asserted bit-identical
-end to end (``query_index`` on the staged path vs ``query_index_compact``);
-CI gates on the flag and the >= 2x front-end speedup.
+Per-stage wall times of the one query path (hash / probe-keys / extents /
+gather / rerank / merge), with the gather and the rerank at two slab
+widths: the worst-case ``(Q, L*P*C)`` slab ``query_index`` takes (mostly
+sentinels — the occupancy figure in the JSON shows how mostly) and the
+pow-2 rung the served path picks from phase A's counts.  End to end, the
+full-slab ``query_index`` and the segmented ``query_compact`` (phase A,
+host rung pick, phase B) are asserted bit-identical; CI gates on the flag.
 
 The skew sweep (ISSUE 6 acceptance) then reruns the compacted back half on
 an occupancy-skewed dataset (Zipfian clusters + duplicated points, so a
@@ -25,7 +23,6 @@ truth).
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import time
 
@@ -34,8 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import pipeline as pipe
-from repro.core.index import (IndexConfig, build_index, query_index,
-                              query_index_compact, probe_index, finish_index)
+from repro.core.index import (IndexConfig, build_index, probe_index,
+                              query_index)
+from repro.core.segments import SegmentedIndex, _finish_segment
 from repro.data import ann_synthetic as ds
 from repro.serve.engine import enable_compilation_cache
 
@@ -55,6 +53,16 @@ def _time(fn, *args, reps=5):
     return float(np.min(ts)) * 1e6, out
 
 
+def _one_segment(cfg, state, ctot_cap, ctot_norm, c_norm):
+    """A one-segment index over ``state`` with its rung caps set."""
+    n = state.dataset.shape[0]
+    idx = SegmentedIndex.from_checkpoint(
+        cfg, state, jnp.arange(n, dtype=jnp.int32), n)
+    seg = idx.segments[0]
+    seg.ctot_cap, seg.ctot_norm, seg.c_norm = ctot_cap, ctot_norm, c_norm
+    return idx
+
+
 def _skew_sweep(smoke: bool, reps: int) -> dict:
     """Two-level capping vs the PR-5 global-cap ladder on skewed data.
 
@@ -66,8 +74,8 @@ def _skew_sweep(smoke: bool, reps: int) -> dict:
     each bucket at the histogram-p99.9 ``c_norm`` and tops the normal
     ladder at ``ctot_norm`` from realized capped totals; the same batch
     lands on the truncate overflow rung at ``ctot_norm`` width.  Timed
-    quantity is phase B (compacted gather + fused rerank, i.e.
-    ``finish_index``) — phase A is policy-independent.
+    quantity is phase B (compacted gather + rerank, i.e.
+    ``segments._finish_segment``) — phase A is policy-independent.
     """
     if smoke:
         spec = ds.DatasetSpec("skew", n=6000, dim=16, universe=256,
@@ -108,27 +116,30 @@ def _skew_sweep(smoke: bool, reps: int) -> dict:
     cb_new, c_new, overflowed = pipe.pick_rung(
         cmax, ctot_cap, 64, ctot_norm, c_norm, "truncate")
 
-    # interleaved phase-B timing (same reasoning as the main shootout: load
-    # drift cancels out of the ratio; best-of-3 rounds is a noise retry)
+    # interleaved phase-B timing (load drift cancels out of the ratio;
+    # best-of-3 rounds is a noise retry)
+    gids = jnp.arange(spec.n, dtype=jnp.int32)
+    tomb = jnp.full((1,), np.iinfo(np.int32).max, jnp.int32)
+
+    def finish(cb, c_cap):
+        return _finish_segment(cfg, cb, c_cap, state, gids, tomb, pk, lo,
+                               occ, queries)[0].block_until_ready()
+
     def sample_round(nreps):
         old_ts, new_ts = [], []
         for _ in range(nreps):
             t0 = time.perf_counter()
-            finish_index(cfg, cb_old, None, state, pk, lo, occ,
-                         queries)[0].block_until_ready()
+            finish(cb_old, None)
             old_ts.append(time.perf_counter() - t0)
             t0 = time.perf_counter()
-            finish_index(cfg, cb_new, c_new, state, pk, lo, occ,
-                         queries)[0].block_until_ready()
+            finish(cb_new, c_new)
             new_ts.append(time.perf_counter() - t0)
         pct = lambda ts, q: float(np.percentile(np.asarray(ts) * 1e6, q))
         return {"old_p50": pct(old_ts, 50), "old_p99": pct(old_ts, 99),
                 "new_p50": pct(new_ts, 50), "new_p99": pct(new_ts, 99)}
 
-    finish_index(cfg, cb_old, None, state, pk, lo, occ, queries)[
-        0].block_until_ready()
-    finish_index(cfg, cb_new, c_new, state, pk, lo, occ, queries)[
-        0].block_until_ready()
+    finish(cb_old, None)
+    finish(cb_new, c_new)
     rounds = []
     for _ in range(3):
         rounds.append(sample_round(max(reps, 11)))
@@ -139,17 +150,14 @@ def _skew_sweep(smoke: bool, reps: int) -> dict:
 
     # correctness: escalate rung bit-identical to the PR-5 policy; truncate
     # rung within 0.5% recall of it against brute-force L1 ground truth
-    d_old, i_old = query_index_compact(cfg, state, queries,
-                                       ctot_cap=ctot_cap)
-    d_esc, i_esc = query_index_compact(
-        cfg, state, queries, ctot_cap=ctot_cap, ctot_norm=ctot_norm,
-        c_cap=c_norm, overflow="escalate")
+    single = _one_segment(cfg, state, ctot_cap, ctot_cap, ctot_cap // lp)
+    d_old, i_old, _ = single.query_compact(queries)
+    two = _one_segment(cfg, state, ctot_cap, ctot_norm, c_norm)
+    d_esc, i_esc, _ = two.query_compact(queries, overflow="escalate")
     identical = bool(np.array_equal(np.asarray(d_old), np.asarray(d_esc))
                      and np.array_equal(np.asarray(i_old),
                                         np.asarray(i_esc)))
-    _, i_tr = query_index_compact(
-        cfg, state, queries, ctot_cap=ctot_cap, ctot_norm=ctot_norm,
-        c_cap=c_norm, overflow="truncate")
+    _, i_tr, _ = two.query_compact(queries, overflow="truncate")
     dist = np.abs(np.asarray(data)[None, :, :].astype(np.int64)
                   - np.asarray(queries)[:, None, :].astype(np.int64)
                   ).sum(-1)
@@ -211,99 +219,60 @@ def main(smoke: bool = False, json_out: str = "BENCH_pipeline.json"):
         q_n, reps = 64, 7
     data = jnp.asarray(ds.make_dataset(spec))
     queries = jnp.asarray(ds.make_queries(spec, np.asarray(data), q_n))
-    staged_cfg = dataclasses.replace(cfg, probe_impl="staged")
     state = build_index(cfg, jax.random.PRNGKey(0), data)
     n = data.shape[0]
     full_slab = cfg.num_tables * cfg.probes_per_table * cfg.candidate_cap
 
-    # -- per-stage breakdown (staged pipeline, worst-case slab) ------------
+    # -- per-stage breakdown -------------------------------------------------
     hash_fn = jax.jit(lambda qs: pipe.stage_hash(cfg, state.params, qs))
     probe_fn = jax.jit(lambda b, x: pipe.stage_probe_keys(
         cfg, state.params, state.template, b, x))
-    lookup_gather_fn = jax.jit(lambda pk: pipe.stage_candidate_gather(
-        cfg, state.sorted_ids,
-        *pipe.stage_bucket_lookup(state.sorted_keys, pk), n))
+    extents_fn = jax.jit(lambda pk: pipe.stage_probe_extents(
+        cfg, state.sorted_keys, pk, state.occ_from))
     rerank_fn = jax.jit(lambda ids: pipe.stage_rerank(
         cfg, state.dataset, queries, ids))
     merge_fn = jax.jit(lambda d, i: pipe.stage_merge_pair(d, i, d, i))
 
+    def gather_fn(cbucket):
+        return jax.jit(lambda pk, lo, occ: pipe.stage_fused_probe(
+            cfg, state.sorted_keys, state.sorted_ids, pk, n, cbucket,
+            extents=(lo, occ)))
+
     us = {}
     us["hash"], (bucket, x_neg) = _time(hash_fn, queries, reps=reps)
     us["probe_keys"], probe_keys = _time(probe_fn, bucket, x_neg, reps=reps)
-    us["lookup_gather_staged"], ids_full = _time(
-        lookup_gather_fn, probe_keys, reps=reps)
-    us["rerank_full_slab"], (rd, ri) = _time(rerank_fn, ids_full, reps=reps)
-    us["merge_pair"], _ = _time(merge_fn, rd, ri, reps=reps)
-
-    # -- fused + compacted front-end (two-phase, host round-trip included) -
-    extents_fn = jax.jit(lambda pk: pipe.stage_probe_extents(
-        cfg, state.sorted_keys, pk, state.occ_from))
-    counts = extents_fn(probe_keys)[2]
+    us["extents"], (lo, occ, counts) = _time(extents_fn, probe_keys,
+                                             reps=reps)
     ctot_cap = (cfg.num_tables * cfg.probes_per_table
                 * min(cfg.candidate_cap,
                       pipe.max_bucket_occupancy(state.sorted_keys,
                                                 state.occ_from)))
     cbucket = pipe.candidate_bucket(int(counts.max()), ctot_cap, floor=64)
-    gather_fn = jax.jit(
-        lambda pk, lo, occ: pipe.stage_fused_probe(
-            cfg, state.sorted_keys, state.sorted_ids, pk, n, cbucket,
-            extents=(lo, occ)),
-        static_argnames=())
-
-    def fused_frontend(pk):
-        lo, occ, c = extents_fn(pk)
-        cb = pipe.candidate_bucket(int(c.max()), ctot_cap, floor=64)
-        assert cb == cbucket  # precompiled rung (engine warmup's job)
-        return gather_fn(pk, lo, occ)
-
-    # compile the picked bucket, then time extents + host pick + gather —
-    # INTERLEAVED with the staged front-end so machine-load drift between
-    # the two measurements cancels out of the ratio the CI gate checks.
-    # The gate quantity is a stable ~2-2.5x on an idle machine but the
-    # fused side takes two dispatches + a host sync per call, so scheduler
-    # jitter hits it asymmetrically — measure up to 3 rounds and gate on
-    # the best one (a noise-floor retry, not a different quantity).
-    fused_frontend(probe_keys)[0].block_until_ready()
-    rounds = []
-    ids_c = None
-    for _ in range(3):
-        staged_ts, fused_ts = [], []
-        for _ in range(max(reps, 9)):
-            t0 = time.perf_counter()
-            lookup_gather_fn(probe_keys)[0].block_until_ready()
-            staged_ts.append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            ids_c, _ = fused_frontend(probe_keys)
-            ids_c.block_until_ready()
-            fused_ts.append(time.perf_counter() - t0)
-        rounds.append((float(np.min(staged_ts)) * 1e6,
-                       float(np.min(fused_ts)) * 1e6))
-        if rounds[-1][0] / rounds[-1][1] >= 2.0:
-            break
-    best = max(rounds, key=lambda r: r[0] / r[1])
-    us["lookup_gather_staged"] = best[0]
-    us["lookup_gather_fused_compact"] = best[1]
-    rerank_c_fn = jax.jit(lambda ids: pipe.stage_rerank(
-        cfg, state.dataset, queries, ids))
-    us["rerank_compact_slab"], _ = _time(rerank_c_fn, ids_c, reps=reps)
+    us["gather_full_slab"], (ids_full, _) = _time(
+        gather_fn(full_slab), probe_keys, lo, occ, reps=reps)
+    us["gather_compact_slab"], (ids_c, _) = _time(
+        gather_fn(cbucket), probe_keys, lo, occ, reps=reps)
+    us["rerank_full_slab"], (rd, ri) = _time(rerank_fn, ids_full, reps=reps)
+    us["rerank_compact_slab"], _ = _time(rerank_fn, ids_c, reps=reps)
+    us["merge_pair"], _ = _time(merge_fn, rd, ri, reps=reps)
 
     # -- end-to-end + bit-identity gate ------------------------------------
-    us["query_staged_e2e"], (sd, si) = _time(
-        lambda qs: query_index(staged_cfg, state, qs), queries, reps=reps)
-    query_index_compact(cfg, state, queries, ctot_cap=ctot_cap)  # compile
-    us["query_compact_e2e"], (cd, ci) = _time(
-        lambda qs: query_index_compact(cfg, state, qs, ctot_cap=ctot_cap),
-        queries, reps=reps)
-    identical = bool(np.array_equal(np.asarray(sd), np.asarray(cd))
-                     and np.array_equal(np.asarray(si), np.asarray(ci)))
+    us["query_full_slab_e2e"], (fd, fi) = _time(
+        lambda qs: query_index(cfg, state, qs), queries, reps=reps)
+    idx = SegmentedIndex.from_checkpoint(
+        cfg, state, jnp.arange(n, dtype=jnp.int32), n)
+    idx.warm_compact(queries)
+    us["query_compact_e2e"], (cd, ci, _) = _time(
+        lambda qs: idx.query_compact(qs), queries, reps=reps)
+    identical = bool(np.array_equal(np.asarray(fd), np.asarray(cd))
+                     and np.array_equal(np.asarray(fi), np.asarray(ci)))
 
     # -- skew sweep: two-level capping vs the global-cap ladder (§9) -------
     skew = _skew_sweep(smoke, reps)
 
-    frontend_speedup = us["lookup_gather_staged"] / us[
-        "lookup_gather_fused_compact"]
+    gather_speedup = us["gather_full_slab"] / us["gather_compact_slab"]
     rerank_speedup = us["rerank_full_slab"] / us["rerank_compact_slab"]
-    e2e_speedup = us["query_staged_e2e"] / us["query_compact_e2e"]
+    e2e_speedup = us["query_full_slab_e2e"] / us["query_compact_e2e"]
     counts_np = np.asarray(counts)
     result = {
         "bench": "pipeline_stages",
@@ -319,22 +288,21 @@ def main(smoke: bool = False, json_out: str = "BENCH_pipeline.json"):
                    "slab_occupancy": round(
                        float(counts_np.mean()) / full_slab, 4)},
         "us_per_call": {k: round(v, 1) for k, v in us.items()},
-        "frontend_speedup": round(frontend_speedup, 3),
+        "gather_speedup_from_compaction": round(gather_speedup, 3),
         "rerank_speedup_from_compaction": round(rerank_speedup, 3),
         "e2e_speedup": round(e2e_speedup, 3),
         "outputs_bit_identical": identical,
         "skew": skew,
         "acceptance": {
             "outputs_bit_identical": identical,
-            "frontend_2x": bool(identical and frontend_speedup >= 2.0),
             **skew["acceptance"],
         },
     }
     with open(json_out, "w") as f:
         json.dump(result, f, indent=1)
-    print(f"pipeline: staged lookup+gather {us['lookup_gather_staged']:.0f}us"
-          f" vs fused+compact {us['lookup_gather_fused_compact']:.0f}us "
-          f"-> {frontend_speedup:.2f}x | slab {full_slab}->{cbucket} "
+    print(f"pipeline: gather full slab {us['gather_full_slab']:.0f}us"
+          f" vs compact {us['gather_compact_slab']:.0f}us "
+          f"-> {gather_speedup:.2f}x | slab {full_slab}->{cbucket} "
           f"(occupancy {result['config']['slab_occupancy']:.1%}) | "
           f"rerank {rerank_speedup:.2f}x e2e {e2e_speedup:.2f}x "
           f"bit_identical={identical} ({json_out})")

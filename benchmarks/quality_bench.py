@@ -81,9 +81,8 @@ def main(smoke: bool = False, json_out: str = "BENCH_quality.json"):
             l_mp is not None and (l_cp is None or l_mp < l_cp)),
         "compacted_matches_fresh": consistency["compacted_matches_fresh"],
         "segmented_matches_flat": consistency["segmented_matches_flat"],
-        "compact_probe_matches_flat": bool(
-            consistency["compact_flat_matches_flat"]
-            and consistency["compact_segmented_matches_flat"]),
+        "compact_segmented_matches_flat":
+            consistency["compact_segmented_matches_flat"],
         "mutated_no_regression": consistency["mutated_no_regression"],
         "dist_matches_flat": consistency["dist_matches_flat"],
         "cluster_matches_flat": consistency["cluster_matches_flat"],

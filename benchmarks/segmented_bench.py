@@ -42,7 +42,11 @@ def main() -> None:
     print(f"monolithic_query,{us:.1f},n={spec.n}")
 
     idx = SegmentedIndex.from_dataset(cfg, key, data, delta_cap=1024)
-    us = _timeit(lambda: idx.query(queries)[0].block_until_ready())
+
+    def served():
+        return idx.query_compact(queries)[0].block_until_ready()
+
+    us = _timeit(served)
     print(f"segmented_query_1seg,{us:.1f},segments=1")
 
     rng = np.random.default_rng(0)
@@ -56,19 +60,19 @@ def main() -> None:
     ins_us = (time.perf_counter() - t0) / total * 1e6
     print(f"insert_per_point,{ins_us:.2f},points={total}")
 
-    us = _timeit(lambda: idx.query(queries)[0].block_until_ready())
+    us = _timeit(served)
     print(f"segmented_query_{idx.num_segments}seg,{us:.1f},"
           f"segments={idx.num_segments} delta_fill={idx.delta_fill:.2f}")
 
     idx.delete(np.arange(0, 512, dtype=np.int32))
-    us = _timeit(lambda: idx.query(queries)[0].block_until_ready())
+    us = _timeit(served)
     print(f"segmented_query_tombstoned,{us:.1f},tombstones={idx.num_tombstones}")
 
     t0 = time.perf_counter()
     idx.compact()
     print(f"compact,{(time.perf_counter() - t0) * 1e6:.0f},live={idx.num_live}")
 
-    us = _timeit(lambda: idx.query(queries)[0].block_until_ready())
+    us = _timeit(served)
     print(f"segmented_query_postcompact,{us:.1f},segments={idx.num_segments}")
 
 

@@ -4,8 +4,8 @@ Streams mixed batch sizes through ``AnnServingEngine`` and demonstrates the
 shape-bucket policy (DESIGN.md §Perf): after ``warmup()`` compiles every
 power-of-two bucket, live traffic with arbitrary batch sizes triggers
 **zero recompiles** (``bucket_cold_hits`` stays 0), and small batches stop
-paying full-batch padding FLOPs.  The legacy pad-to-batch_size policy is
-measured side by side.  Emits machine-readable ``BENCH_serving.json``.
+paying full-batch padding FLOPs.  Emits machine-readable
+``BENCH_serving.json``.
 """
 from __future__ import annotations
 
@@ -199,14 +199,7 @@ def main(smoke: bool = False, json_out: str = "BENCH_serving.json",
         "config": {"n": spec.n, "dim": spec.dim, "batch_size": batch,
                    "bursts": len(bursts)},
         "bucketed": run_engine(
-            cfg, ServeConfig(batch_size=batch, delta_cap=256,
-                             shape_buckets=True), data, bursts),
-        "legacy_fixed": run_engine(
-            cfg, ServeConfig(batch_size=batch, delta_cap=256,
-                             shape_buckets=False), data, bursts),
-        "full_slab": run_engine(
-            cfg, ServeConfig(batch_size=batch, delta_cap=256,
-                             compact_probe=False), data, bursts),
+            cfg, ServeConfig(batch_size=batch, delta_cap=256), data, bursts),
         "compilation_cache": compilation_cache_stats(),
     }
     if not skip_warm_start:
@@ -217,12 +210,11 @@ def main(smoke: bool = False, json_out: str = "BENCH_serving.json",
     result["zero_recompiles_after_warmup"] = ok
     with open(json_out, "w") as f:
         json.dump(result, f, indent=1)
-    b, l = result["bucketed"], result["legacy_fixed"]
+    b = result["bucketed"]
     ws = result.get("warm_start", {})
     print(f"serving buckets={b['buckets']} cand_buckets={b['cand_buckets']} "
           f"recompiles_after_warmup={b['recompiles_after_warmup']} "
-          f"p50={b['p50_batch_ms']}ms (legacy p50={l['p50_batch_ms']}ms, "
-          f"full-slab p50={result['full_slab']['p50_batch_ms']}ms) "
+          f"p50={b['p50_batch_ms']}ms "
           f"warm_start x{ws.get('startup_speedup', 'skipped')} "
           f"obs_overhead={result['trace_off_overhead']['frac_of_p50']:.4%} "
           f"of p50 -> {json_out}")

@@ -12,8 +12,8 @@ it, serves 256 in-distribution queries in batches of 64, then inserts
 deletes 300 and serves again.  It checks that
 
   * recall@10 against brute-force L1 on the chip is at least 0.9;
-  * before mutation, served results equal the flat
-    ``query_index_compact`` on the same chip, bit for bit;
+  * before mutation, served results equal the flat full-slab
+    ``query_index`` on the same chip, bit for bit;
   * deleted gids never come back;
   * every inserted row that is still live finds itself.
 
@@ -115,7 +115,7 @@ def run_one_chip(shape: Shape, cfg, serve_cfg, seed: int, log) -> dict:
     import numpy as np
 
     from repro.core import baselines as bl
-    from repro.core.index import query_index_compact
+    from repro.core.index import query_index
     from repro.serve.engine import (AnnServingEngine,
                                     compilation_cache_stats,
                                     enable_compilation_cache)
@@ -162,7 +162,7 @@ def run_one_chip(shape: Shape, cfg, serve_cfg, seed: int, log) -> dict:
                   "smoke_batch_ms_p99": float(np.percentile(ms1, 99))})
 
     seg = engine.index.segments[0]
-    flat = [query_index_compact(cfg, seg.state, jnp.asarray(c))
+    flat = [query_index(cfg, seg.state, jnp.asarray(c))
             for c in _batches(queries, shape.batch)]
     fd = np.concatenate([np.asarray(f[0]) for f in flat])
     fi = np.concatenate([np.asarray(f[1]) for f in flat])
@@ -300,8 +300,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    from repro.kernels import ops as kops
-
     devices = jax.devices()
     dev = {"platform": devices[0].platform, "kind": devices[0].device_kind,
            "count": len(devices)}
@@ -310,7 +308,6 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     _log("device", dev)
-    _log("executors", kops.executors())
 
     if args.chips == 4:
         checks = run_four_chips(SIFT4M, index_config(SIFT4M), args.seed,

@@ -68,7 +68,7 @@ def main():
     mgr.save(1, payload)
     r_state, r_gids, r_next = mgr.restore(1, payload)
     node = SegmentedIndex.from_checkpoint(cfg, r_state, r_gids, r_next)
-    d2, i2 = node.query(jnp.asarray(q))
+    d2, i2, _ = node.query_compact(jnp.asarray(q))
     same = bool((np.asarray(d2) == d).all()) and bool((np.asarray(i2) == i).all())
     print("restored-node results identical:", same)
     assert same

@@ -37,18 +37,17 @@ __all__ = ["HostSyncRule", "RecompileHazardRule", "WireProtocolRule",
 # -- shared vocabulary ------------------------------------------------------
 
 # calls that return device arrays (jitted entry points, pipeline stages,
-# kernel executors); method or function position, terminal name match
+# kernel wrappers); method or function position, terminal name match
 DEVICE_FNS = {
-    "query", "query_compact", "warm_compact",
-    "probe_index", "finish_index", "query_index", "query_index_compact",
+    "query_compact", "warm_compact",
+    "probe_index", "finish_query", "query_index",
     "build_index",
-    "_query_segment", "_query_delta", "_probe_segment", "_finish_segment",
+    "_query_delta", "_probe_segment", "_finish_segment",
     "_truncated_total",
-    "stage_hash", "stage_probe_keys", "stage_bucket_lookup",
-    "stage_candidate_gather", "stage_probe_extents", "stage_probe_counts",
-    "stage_fused_probe", "stage_dedup", "stage_tombstone", "stage_rerank",
-    "stage_merge_pair", "stage_merge_concat",
-    "probe_candidates", "rerank_candidates", "l1_distance_chunked",
+    "stage_hash", "stage_probe_keys", "stage_probe_extents",
+    "stage_probe_counts", "stage_fused_probe", "stage_tombstone",
+    "stage_rerank", "stage_merge_pair", "stage_merge_concat",
+    "rerank_candidates", "l1_distance_chunked",
     "fused_probe", "fused_rerank", "topk_merge",
 }
 
@@ -202,7 +201,7 @@ class RecompileHazardRule(Rule):
     # static shape-bearing arguments
     CONSUMERS: Dict[str, Tuple[Tuple[int, ...], Tuple[str, ...]]] = {
         "_finish_segment": ((1, 2), ("cbucket", "c_cap")),
-        "finish_index": ((1, 2), ("cbucket", "c_cap")),
+        "finish_query": ((1, 2), ("cbucket", "c_cap")),
         "stage_fused_probe": ((5,), ("cbucket", "c_cap")),
     }
 

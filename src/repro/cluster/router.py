@@ -14,7 +14,7 @@ one logical index with the flat ``query_index`` contract:
     comparable with a flat index over the same rows;
   * **query fan-out** — a batch is padded ONCE to the engines' shared
     shape bucket, sent to every shard (one replica each), per-shard top-k
-    folded pairwise with the bitonic ``topk_merge`` kernel
+    folded pairwise with the ``topk_merge`` op
     (``pipeline.stage_merge_pair`` — the same fold the segmented index and
     the distributed ring merge use), then sliced back to the live rows.
     Every source returns its exact top-k over its own candidates, so with

@@ -19,10 +19,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.rows import widen_rows
+
 from . import hashes as hashes_lib
-from .index import IndexConfig, build_index, query_index, l1_distance_chunked
+from .index import IndexConfig
+from .pipeline import BIG_DIST
 
 __all__ = [
+    "l1_distance_chunked",
     "brute_force_l1",
     "single_probe_config",
     "cp_lsh_config",
@@ -33,6 +37,49 @@ __all__ = [
     "recall",
     "overall_ratio",
 ]
+
+
+def l1_distance_chunked(
+    dataset: jax.Array, queries: jax.Array, ids: jax.Array, k: int,
+    chunk: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Exact L1 scan of given candidates: a chunked ``lax.top_k`` running best.
+
+    The reference's plain scan (``brute_force_l1``, ``query_srs``), not a
+    serving executor: the query path reranks with ``kops.fused_rerank``.
+    dataset (n, m) stored rows (``kernels.rows``); queries (Q, m) int; ids
+    (Q, Ctot) int32 with sentinel n marking invalid, **unique** (a
+    duplicate would take a top-k slot of its own).  Returns (dists (Q,k)
+    int32, ids (Q,k) int32) sorted ascending; invalid entries have dist =
+    INT32_MAX/2 and id = -1.
+    """
+    n = dataset.shape[0]
+    q, ctot = ids.shape
+    big = jnp.int32(BIG_DIST)
+    pad = (-ctot) % chunk
+    if pad:
+        ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=n)
+    steps = ids.shape[1] // chunk
+    ids_steps = ids.reshape(q, steps, chunk).transpose(1, 0, 2)     # (S,Q,c)
+
+    def body(carry, step_ids):
+        best_d, best_i = carry                                      # (Q,k)
+        sl = jnp.clip(step_ids, 0, n - 1)                           # (Q,c)
+        rows = dataset[sl]                                          # (Q,c,m)
+        # HBM gather stays at the stored dtype (int16 or uint8); the rows
+        # are widened to int32 coordinates in registers.
+        diff = widen_rows(rows) - queries[:, None, :].astype(jnp.int32)
+        d = jnp.abs(diff).sum(axis=-1).astype(jnp.int32)
+        d = jnp.where(step_ids >= n, big, d)
+        cd = jnp.concatenate([best_d, d], axis=-1)
+        ci = jnp.concatenate([best_i, step_ids], axis=-1)
+        nd, sel = jax.lax.top_k(-cd, k)
+        return (-nd, jnp.take_along_axis(ci, sel, axis=-1)), None
+
+    init = (jnp.full((q, k), big, jnp.int32), jnp.full((q, k), n, jnp.int32))
+    (best_d, best_i), _ = jax.lax.scan(body, init, ids_steps)
+    best_i = jnp.where(best_d >= big, -1, best_i)
+    return best_d, best_i
 
 
 @partial(jax.jit, static_argnums=(2, 3))

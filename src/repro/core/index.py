@@ -6,10 +6,11 @@ TPU-idiomatic design described in DESIGN.md Sect. 2:
   build : raw-hash all points -> bucket vectors -> uint32 mixed keys ->
           one sort per table.  Collective-free; embarrassingly shardable by
           dataset rows.
-  query : the staged pipeline of ``core.pipeline`` (hash -> probe-gen ->
-          bucket-lookup -> candidate-gather -> dedup -> exact L1 rerank),
-          composed here over an ``IndexState``.  The distributed path and
-          the serving engine compose the same stages (DESIGN.md Sect. 3).
+  query : phase A (``probe_index``: hash -> probe keys -> bucket extents
+          + counts), then phase B (``finish_query``: compacted gather ->
+          exact L1 rerank -> gid map -> tombstones), composed here over an
+          ``IndexState``.  The segmented index, the shard_map path and the
+          serving engine call the same two (DESIGN.md Sect. 3).
 
 Everything is statically shaped and jit/vmap/shard_map friendly.  For the
 mutable (insert/delete/compact) variant see ``core.segments``.
@@ -27,12 +28,10 @@ import numpy as np
 from . import hashes as hashes_lib
 from . import multiprobe as mp_lib
 from . import pipeline as pipe
-from .pipeline import l1_distance_chunked  # re-export (legacy import path)
 from repro.kernels.rows import STORED_DTYPES, UINT8_MAX_UNIVERSE, store_rows
 
 __all__ = ["IndexConfig", "IndexState", "build_index", "query_index",
-           "probe_index", "finish_index", "query_index_compact",
-           "l1_distance_chunked"]
+           "probe_index", "finish_query", "row_gids", "no_tombstones"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,9 +47,6 @@ class IndexConfig:
     family: str = "rw"           # 'rw' | 'cauchy' | 'gaussian'
     hash_impl: str = "gather"    # 'gather' | 'thermo' | 'pallas'
     rerank_chunk: int = 512      # candidates per rerank scan step
-    rerank_impl: str = "fused"   # 'fused' (kernel, sort-free dedup) | 'scan'
-    probe_impl: str = "fused"    # 'fused' (lookup+gather kernel, compactable
-                                 # slab) | 'staged' (legacy two-stage pair)
     k: int = 50                  # neighbors returned
     dataset_dtype: str = "int32" # stored rows (``kernels.rows``): 'int16'
                                  # halves the rerank gather's bytes when
@@ -89,7 +85,7 @@ class IndexState:
     occ_from    : (L, n) int32    equal-key run length starting at each
                   position (DESIGN.md §8): a probed bucket's occupancy is
                   ``occ_from[lo]`` (every searchsorted-left hit lands on a
-                  run start), so the fused probe front-end needs no
+                  run start), so the probe's phase A needs no
                   ``side='right'`` search.  Optional (None on legacy/
                   abstract states; the extents then fall back to the
                   two-sided search).
@@ -291,43 +287,17 @@ def _occ_histogram(sorted_keys: jax.Array, occ_from: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
-# Query path
-# --------------------------------------------------------------------------
-
-def _probe_candidate_ids(cfg: IndexConfig, state: IndexState, queries: jax.Array):
-    """Multi-probe -> candidate local row ids (pipeline stages 1-5).
-
-    returns ids (Q, L*P*C) int32 (sentinel n for invalid) — always
-    deduplicated (debug/test helper; the query path lets the fused rerank
-    kernel dedup instead, see ``pipeline.rerank_handles_duplicates``).
-    """
-    return pipe.probe_candidates(
-        cfg, state.params, state.template, state.sorted_keys,
-        state.sorted_ids, state.dataset.shape[0], queries, dedup=True)
-
-
-@partial(jax.jit, static_argnums=0)
-def query_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
-    """Batched ANN query.  Returns (dists (Q,k) int32, global_ids (Q,k) int32)."""
-    ids = pipe.probe_candidates(
-        cfg, state.params, state.template, state.sorted_keys,
-        state.sorted_ids, state.dataset.shape[0], queries)
-    d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
-    gid = jnp.where(i >= 0, i + state.row_offset, -1)
-    return d, gid
-
-
-# --------------------------------------------------------------------------
-# Compacted two-phase query (DESIGN.md §8)
+# Query path (DESIGN.md §8)
 # --------------------------------------------------------------------------
 #
-# ``query_index`` is one jit with a static worst-case candidate slab.  The
-# compacted path splits at the only data-dependent decision — how wide a
-# slab this batch actually needs — into two jitted phases with one scalar
-# host read between them: probe (hash + probe keys + candidate counts),
-# then gather+rerank at a pow-2 candidate bucket.  Output is bit-identical
-# to ``query_index`` (the rerank contract depends only on the candidate
-# set); only the padding work shrinks.
+# Every query runs phase A (``probe_index``: probe keys, bucket extents and
+# candidate counts), then phase B (``finish_query``: the gather at a static
+# slab width, the rerank and the tombstone check).  The served path splits
+# them into two jitted programs with one scalar host read between them, the
+# batch's max count, which picks the narrowest rung that covers it
+# (``pipeline.pick_rung``); ``query_index`` and the shard_map body take the
+# full ``L*P*C`` slab in one program instead.  The rerank contract depends
+# only on the candidate set, so every covering width gives the same bits.
 
 @partial(jax.jit, static_argnums=0)
 def probe_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
@@ -335,9 +305,8 @@ def probe_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
 
     Returns (probe_keys (Q, L, P), lo (Q, L*P), occ (Q, L*P) raw bucket
     occupancies, counts (Q,)).  The extents cross the host-side rung pick
-    so phase B never re-searches (XLA backends); the probe keys ride along
-    for the Pallas executor, which re-searches in VMEM instead (each
-    backend's unused input is dead-code-eliminated).
+    so phase B never re-searches; phase B reads only the probe keys'
+    shape.
     """
     # The scopes name this program's device ops in a profiler trace; the
     # counts' own scope is inside the extents (``counts``).
@@ -352,46 +321,49 @@ def probe_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
     return probe_keys, lo, occ, counts
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def finish_index(cfg: IndexConfig, cbucket: int, c_cap: Optional[int],
-                 state: IndexState, probe_keys: jax.Array, lo: jax.Array,
+def finish_query(cfg: IndexConfig, cbucket: Optional[int],
+                 c_cap: Optional[int], state: IndexState, gids: jax.Array,
+                 tombstones: jax.Array, probe_keys: jax.Array, lo: jax.Array,
                  occ: jax.Array, queries: jax.Array):
-    """Phase B: compacted gather at the (static) rung + rerank.
+    """Phase B: compacted gather at the (static) rung -> rerank at k + T
+    -> gid map -> tombstone -> first k (``pipe.rerank_candidates``).
 
-    ``c_cap=None`` keeps the full per-bucket clamp (exact); an int is the
-    two-level truncate rung's tighter cap (DESIGN.md §9).
+    ``cbucket=None`` is the full ``L*P*C`` slab.  ``c_cap=None`` keeps the
+    full per-bucket clamp (exact); an int is the two-level truncate rung's
+    tighter cap (deterministic sorted-prefix truncation, DESIGN.md §9).
+    ``gids`` (n,) maps local rows to global ids (``row_gids`` for a flat
+    shard); ``tombstones`` is ascending and INT32_MAX-padded
+    (``no_tombstones`` for none).  Not jitted itself: each caller's own
+    program holds it.
     """
     n = state.dataset.shape[0]
-    ids, _ = pipe.stage_fused_probe(
-        cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
-        extents=(lo, occ), c_cap=c_cap)
-    if not pipe.rerank_handles_duplicates(cfg):
-        ids = pipe.stage_dedup(ids, n)
-    d, i = pipe.stage_rerank(cfg, state.dataset, queries, ids)
-    gid = jnp.where(i >= 0, i + state.row_offset, -1)
-    return d, gid
+    # The scopes name this program's device ops in a profiler trace.
+    with jax.named_scope("compact_gather"):
+        ids, _ = pipe.stage_fused_probe(
+            cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
+            extents=(lo, occ), c_cap=c_cap)
+    return pipe.rerank_candidates(cfg, state.dataset, queries, ids, gids,
+                                  tombstones)
 
 
-def query_index_compact(cfg: IndexConfig, state: IndexState,
-                        queries: jax.Array, floor: int = 64,
-                        ctot_cap: Optional[int] = None,
-                        ctot_norm: Optional[int] = None,
-                        c_cap: Optional[int] = None,
-                        overflow: str = "escalate"):
-    """Two-phase compacted query; bit-identical to ``query_index`` on the
-    normal and ``escalate`` paths.
+def row_gids(state: IndexState) -> jax.Array:
+    """(n,) global id of each local row: ``row_offset`` + row."""
+    return (jnp.arange(state.dataset.shape[0], dtype=jnp.int32)
+            + state.row_offset)
 
-    ``ctot_cap`` bounds the ladder top (pass
-    ``pipe.max_bucket_occupancy``-derived caps when known); defaults to the
-    static worst case L*P*C.  ``ctot_norm``/``c_cap``/``overflow`` enable
-    the two-level ladder (DESIGN.md §9): batches whose max count exceeds
-    ``ctot_norm`` either escalate to the exact ``ctot_cap`` rung or run the
-    bounded ``(ctot_norm, c_cap)`` truncate rung.
+
+def no_tombstones() -> jax.Array:
+    """The tombstone array with nothing deleted: one INT32_MAX pad slot,
+    the array ``SegmentedIndex`` ships before any delete."""
+    return jnp.full((1,), np.iinfo(np.int32).max, jnp.int32)
+
+
+@partial(jax.jit, static_argnums=0)
+def query_index(cfg: IndexConfig, state: IndexState, queries: jax.Array):
+    """Batched ANN query at the full slab, one program, no host read.
+
+    Returns (dists (Q,k) int32, global_ids (Q,k) int32).
     """
-    if ctot_cap is None:
-        ctot_cap = (cfg.num_tables * cfg.probes_per_table
-                    * cfg.candidate_cap)
-    probe_keys, lo, occ, counts = probe_index(cfg, state, queries)
-    cb, cc, _ = pipe.pick_rung(int(counts.max()), ctot_cap, floor,  # repro: allow[r1-host-sync] THE sanctioned phase-A rung-pick read (DESIGN.md §8)
-                               ctot_norm, c_cap, overflow)
-    return finish_index(cfg, cb, cc, state, probe_keys, lo, occ, queries)
+    probe_keys, lo, occ, _ = probe_index(cfg, state, queries)
+    return finish_query(cfg, None, None, state, row_gids(state),
+                        no_tombstones(), probe_keys, lo, occ, queries)

@@ -1,25 +1,27 @@
-"""Staged MP-RW-LSH query pipeline (DESIGN.md Sect. 3).
+"""MP-RW-LSH query stages (DESIGN.md Sect. 3).
 
-The query flow is decomposed into pure, statically-shaped stages
+Every query, flat, segmented, sharded or served, runs one composition:
 
-    hash -> probe-gen -> bucket-lookup -> candidate-gather -> dedup
-         -> rerank at k + T -> gid map [-> tombstone -> first k] -> merge
+    phase A : hash -> probe keys -> bucket extents + candidate counts
+    rung    : one host read of the batch's max count picks a static slab
+              width (``pick_rung``; the flat and sharded paths take the
+              full ``L*P*C`` slab instead and read nothing)
+    phase B : compacted gather at the rung -> rerank at k + T -> gid map
+              -> tombstone -> first k
+    merge   : per-segment or per-shard (Q, k) lists folded by (dist, id)
 
-so that the single-shard path (``core.index.query_index``), the shard_map
-path (``launch.dist_index``), and the serving engine (``serve.engine``)
-compose the *same* functions instead of re-implementing the flow.  Every
-stage takes raw arrays (no ``IndexState``), which is what lets the
-shard_map body call them on its per-shard slices directly.
+``core.index.probe_index`` is phase A and ``core.index.finish_query`` is
+phase B; the flat query (``core.index.query_index``), the segmented index
+(``core.segments``), the shard_map body (``launch.dist_index``) and the
+serving engine all call those two.  The stages here take raw arrays (no
+``IndexState``), each with one executor (``kernels.ops``).
 
 Stage contracts (Q queries, L tables, M hashes, P probes/table, C cap):
 
   stage_hash       : queries (Q, m)            -> bucket, x_neg (Q, L, M)
   stage_probe_keys : bucket, x_neg             -> probe_keys (Q, L, P) uint32
-  stage_bucket_lookup : sorted_keys, probe_keys -> lo, hi (Q, L, P)
-  stage_candidate_gather : sorted_ids, lo, hi  -> ids (Q, L*P*C), sentinel n
-  stage_probe_counts : sorted_keys, probe_keys -> counts (Q,) valid cands
-  stage_fused_probe : sorted_keys/ids, probe_keys -> ids (Q, Cb), counts (Q,)
-  stage_dedup      : ids                       -> ids, duplicates -> sentinel
+  stage_probe_extents : sorted_keys, probe_keys -> lo, occ (Q, L*P), counts (Q,)
+  stage_fused_probe : sorted_ids, extents      -> ids (Q, Cb), counts (Q,)
   stage_rerank     : dataset, queries, ids     -> (dists, ids) (Q, k) asc
   stage_tombstone  : (dists, gids) (Q, k + T), tombstones
                                                -> first k live (Q, k) asc
@@ -27,33 +29,21 @@ Stage contracts (Q queries, L tables, M hashes, P probes/table, C cap):
   stage_merge_pair : two (Q, k) ascending lists -> one (Q, k) ascending list
   stage_merge_concat : (Q, R*k) stacked lists  -> (Q, k)
 
+The gather packs valid candidates to the front of a ``(Q, cbucket)`` slab,
+duplicates (a point probed from several tables) included; the rerank drops
+duplicates itself and returns the k lexicographically-(dist, id)-smallest
+unique candidates.  That contract depends only on the candidate *set*, so
+any rung that covers the batch's counts gives the bits of the full slab —
+which is also exactly what the pre-refactor monolithic ``query_index``
+computed (tests/test_segments.py proves it against a frozen copy of the
+seed implementation).
+
 Tombstones (DESIGN.md §3.1) apply after selection, not before it: the
 rerank selects the k + T lex-(dist, id)-smallest unique candidates, where T
 is the static length of the tombstone array; at most T of them are dead,
 so the first k live ones among them are exactly the k a per-candidate mask
 ahead of the rerank would leave.  The check then costs Q * (k + T) lookups
 instead of one per slot of the candidate slab.
-
-Probe dispatch (DESIGN.md §8): ``cfg.probe_impl`` selects between the fused
-lookup+gather kernel (``kernels/fused_probe``, the default) and the legacy
-staged ``stage_bucket_lookup`` + ``stage_candidate_gather`` pair.  The fused
-path packs valid candidates to the front of the slab and can emit a
-**compacted** ``(Q, cbucket)`` slab when the caller passes a static
-``cbucket`` (picked from ``stage_probe_counts`` via ``candidate_bucket`` —
-the same pow-2 shape-bucket discipline the serving engine uses for batch
-sizes).  The rerank contract is order/width-invariant over the candidate
-*set*, so every choice yields bit-identical final (dists, ids).
-
-Rerank dispatch (DESIGN.md §Perf): ``cfg.rerank_impl`` selects between the
-fused gather+L1+running-top-k kernel (``kernels/fused_rerank``, the default)
-and the legacy chunked scan + ``lax.top_k`` (``l1_distance_chunked``).  The
-fused kernel suppresses duplicate candidate ids itself via id-keyed masking,
-so ``probe_candidates`` skips the sorting dedup stage entirely on that path
-(sort-free dedup).  Both paths produce bit-identical results — the k
-lexicographically-(dist, id)-smallest unique candidates — which is also
-exactly what the pre-refactor monolithic ``query_index`` computed
-(tests/test_segments.py proves it against a frozen copy of the seed
-implementation; tests/test_fused_rerank.py pins the kernel executors).
 """
 from __future__ import annotations
 
@@ -64,7 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as kops
-from repro.kernels.rows import widen_rows
 
 from . import hashes as hashes_lib
 from . import multiprobe as mp_lib
@@ -73,20 +62,15 @@ __all__ = [
     "BIG_DIST",
     "stage_hash",
     "stage_probe_keys",
-    "stage_bucket_lookup",
-    "stage_candidate_gather",
+    "stage_probe_extents",
     "stage_probe_counts",
     "stage_fused_probe",
-    "stage_dedup",
-    "probe_candidates",
-    "rerank_handles_duplicates",
     "stage_rerank",
     "stage_tombstone",
     "tomb_select",
     "rerank_candidates",
     "stage_merge_pair",
     "stage_merge_concat",
-    "l1_distance_chunked",
     "max_bucket_occupancy",
     "oracle_candidate_cap",
     "occupancy_quantile",
@@ -124,58 +108,18 @@ def stage_probe_keys(
     return probe_keys.transpose(0, 2, 1)                        # (Q, L, P)
 
 
-def stage_bucket_lookup(sorted_keys: jax.Array, probe_keys: jax.Array):
-    """searchsorted per table.  Returns (lo, hi) (Q, L, P) bucket extents."""
-
-    def per_table(sk, pk):  # sk (n,), pk (Q, P)
-        lo = jnp.searchsorted(sk, pk, side="left")
-        hi = jnp.searchsorted(sk, pk, side="right")
-        return lo, hi
-
-    return jax.vmap(per_table, in_axes=(0, 1), out_axes=1)(
-        sorted_keys, probe_keys)
-
-
-def stage_candidate_gather(
-    cfg, sorted_ids: jax.Array, lo: jax.Array, hi: jax.Array, n: int,
-) -> jax.Array:
-    """Gather up to candidate_cap row ids per probed bucket.
-
-    Returns (Q, L*P*C) int32 local ids with sentinel n for empty slots.
-    """
-    q = lo.shape[0]
-    l, p, c = cfg.num_tables, cfg.probes_per_table, cfg.candidate_cap
-    if n == 0:
-        # Zero-point shard (e.g. a segment compacted down to nothing, or an
-        # empty seed): clip(slots, 0, n-1) is ill-formed and the id gather
-        # would read a zero-length array.  Every slot is invalid, and the
-        # sentinel for n=0 is 0 itself.
-        return jnp.zeros((q, l * p * c), jnp.int32)
-    slots = lo[..., None] + jnp.arange(c, dtype=lo.dtype)       # (Q,L,P,C)
-    valid = slots < jnp.minimum(hi, lo + c)[..., None]
-    slots = jnp.clip(slots, 0, n - 1)
-
-    def gather_ids(sid, sl):  # sid (n,), sl (Q, P, C)
-        return sid[sl]
-
-    ids = jax.vmap(gather_ids, in_axes=(0, 1), out_axes=1)(
-        sorted_ids, slots)                                      # (Q,L,P,C)
-    return jnp.where(valid, ids, n).reshape(q, l * p * c)
-
-
 def stage_probe_extents(cfg, sorted_keys: jax.Array, probe_keys: jax.Array,
                         occ_from=None):
-    """Raw bucket extents + per-query candidate counts — the fused
-    front-end's phase A.
+    """Raw bucket extents + per-query candidate counts — phase A.
 
     Returns (lo (Q, L*P) int32, occ (Q, L*P) int32 — *unclamped* per-bucket
     occupancies — and counts (Q,) int32 totals under ``cfg.candidate_cap``).
     The two-phase serving path runs this as its own jitted phase, pulls
     ``counts.max()`` to the host, picks a rung (``pick_rung``), and hands
     (lo, occ) back to ``stage_fused_probe`` so the gather phase neither
-    re-searches nor re-scans.  The counts are exactly what the fused probe
-    kernel reports, so a bucket >= the max count can never truncate; the
-    raw occupancies let the overflow rung apply a tighter per-bucket cap
+    re-searches nor re-scans.  The counts are exactly what the gather
+    reports, so a bucket >= the max count can never truncate; the raw
+    occupancies let the overflow rung apply a tighter per-bucket cap
     (``c_cap``) to the same extents (DESIGN.md §9).
 
     ``occ_from`` (``IndexState.occ_from``, the build-time run-length table)
@@ -197,17 +141,17 @@ def stage_fused_probe(
     probe_keys: jax.Array, n: int, cbucket: Optional[int] = None,
     extents=None, c_cap: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Fused bucket-lookup + compacted candidate gather (DESIGN.md §8).
+    """Bucket lookup + compacted candidate gather (DESIGN.md §8).
 
     Returns (ids (Q, cbucket) int32 sentinel n — valid candidates packed to
     the front in (table, probe, offset) order, counts (Q,) int32).
-    ``cbucket`` defaults to the full worst-case ``L*P*C`` width (still
-    fused — the (Q, L, P, C) slab never exists — just not compacted); a
+    ``cbucket`` defaults to the full worst-case ``L*P*C`` width (the
+    (Q, L, P, C) slab never exists, but the slab is not narrowed); a
     caller-picked static ``cbucket`` shrinks the slab the rerank pays for.
     ``cbucket`` must cover the actual counts or the tail candidates are
     dropped (callers derive it from ``stage_probe_extents``, whose (lo,
-    occ) pair can be passed back here as ``extents`` to skip the re-search
-    on XLA backends).  ``c_cap`` tightens the per-bucket cap below
+    occ) pair can be passed back here as ``extents`` to skip the
+    re-search).  ``c_cap`` tightens the per-bucket cap below
     ``cfg.candidate_cap`` — the two-level truncate rung (DESIGN.md §9);
     truncation is the deterministic sorted-order prefix of each bucket.
     """
@@ -386,146 +330,27 @@ def pick_rung(count: int, ctot_cap: int, floor: int = 64,
     raise ValueError(f"unknown overflow policy: {overflow!r}")
 
 
-def rerank_handles_duplicates(cfg) -> bool:
-    """True when ``stage_rerank``'s implementation suppresses duplicates.
-
-    The fused rerank kernel dedups via id-keyed masking (DESIGN.md §Perf),
-    so the pipeline's sorting ``stage_dedup`` becomes redundant work and
-    ``probe_candidates`` skips it (the sort-free dedup path).  Only the
-    legacy ``scan`` impl still needs the pre-sort.
-    """
-    return getattr(cfg, "rerank_impl", "fused") != "scan"
-
-
-def stage_dedup(ids: jax.Array, n: int) -> jax.Array:
-    """Sort ascending; equal-adjacent -> sentinel n.
-
-    Guarantees no candidate is reranked twice even when it falls in several
-    tables/probes (sentinel slots sort to the tail and stay sentinel).
-    Skipped when the fused rerank kernel dedups internally — see
-    ``rerank_handles_duplicates``.
-    """
-    q = ids.shape[0]
-    ids = jnp.sort(ids, axis=-1)
-    dup = jnp.concatenate(
-        [jnp.zeros((q, 1), bool), ids[:, 1:] == ids[:, :-1]], axis=-1)
-    return jnp.where(dup, n, ids)
-
-
-def probe_candidates(
-    cfg, params: hashes_lib.LshParams, template: jax.Array,
-    sorted_keys: jax.Array, sorted_ids: jax.Array, n: int,
-    queries: jax.Array, dedup: Optional[bool] = None,
-    cbucket: Optional[int] = None, c_cap: Optional[int] = None,
-) -> jax.Array:
-    """hash -> probe-gen -> lookup+gather [-> dedup], composed.
-
-    Returns candidate local ids, sentinel n.  The lookup+gather runs per
-    ``cfg.probe_impl``: 'fused' (default) uses the fused front-end kernel
-    (valid candidates packed first; slab width ``cbucket`` when given, else
-    the worst-case L*P*C; per-bucket cap tightened to ``c_cap`` when given
-    — the two-level truncate rung), 'staged' the legacy two-stage pair at
-    fixed L*P*C width (``cbucket``/``c_cap`` unsupported there).  ``dedup``
-    defaults to cfg-driven: the sorting dedup only runs when the configured
-    rerank impl does not dedup internally (``rerank_handles_duplicates``);
-    the fused rerank consumes the raw gather and masks duplicates
-    in-kernel.
-    """
-    bucket, x_neg = stage_hash(cfg, params, queries)
-    probe_keys = stage_probe_keys(cfg, params, template, bucket, x_neg)
-    impl = getattr(cfg, "probe_impl", "fused")
-    if impl == "fused":
-        ids, _ = stage_fused_probe(
-            cfg, sorted_keys, sorted_ids, probe_keys, n, cbucket,
-            c_cap=c_cap)
-    elif impl == "staged":
-        if cbucket is not None or c_cap is not None:
-            raise ValueError("slab compaction requires probe_impl='fused'")
-        lo, hi = stage_bucket_lookup(sorted_keys, probe_keys)
-        ids = stage_candidate_gather(cfg, sorted_ids, lo, hi, n)
-    else:
-        raise ValueError(f"unknown probe_impl: {impl!r}")
-    if dedup is None:
-        dedup = not rerank_handles_duplicates(cfg)
-    return stage_dedup(ids, n) if dedup else ids
-
-
 # --------------------------------------------------------------------------
 # Rerank + merge stages
 # --------------------------------------------------------------------------
 
-def l1_distance_chunked(
-    dataset: jax.Array, queries: jax.Array, ids: jax.Array, k: int,
-    chunk: int,
-) -> Tuple[jax.Array, jax.Array]:
-    """Legacy exact L1 rerank: chunked scan with a ``lax.top_k`` running best.
-
-    dataset (n, m) stored rows (``kernels.rows``); queries (Q, m) int; ids
-    (Q, Ctot) int32 with sentinel n marking invalid, **deduplicated**
-    (duplicates would each take a top-k slot here — feed it
-    ``stage_dedup`` output).  Returns (dists (Q,k) int32,
-    ids (Q,k) int32) sorted ascending; invalid entries have dist =
-    INT32_MAX/2 and id = -1.
-
-    Kept as the `scan` rerank impl and as the benchmark baseline; the fused
-    kernel path (DESIGN.md §Perf) avoids this function's per-chunk HBM
-    round-trips and repeated top_k.
-    """
-    n = dataset.shape[0]
-    q, ctot = ids.shape
-    big = jnp.int32(BIG_DIST)
-    pad = (-ctot) % chunk
-    if pad:
-        ids = jnp.pad(ids, ((0, 0), (0, pad)), constant_values=n)
-    steps = ids.shape[1] // chunk
-    ids_steps = ids.reshape(q, steps, chunk).transpose(1, 0, 2)     # (S,Q,c)
-
-    def body(carry, step_ids):
-        best_d, best_i = carry                                      # (Q,k)
-        sl = jnp.clip(step_ids, 0, n - 1)                           # (Q,c)
-        rows = dataset[sl]                                          # (Q,c,m)
-        # HBM gather stays at the stored dtype (int16 or uint8); the rows
-        # are widened to int32 coordinates in registers.
-        diff = widen_rows(rows) - queries[:, None, :].astype(jnp.int32)
-        d = jnp.abs(diff).sum(axis=-1).astype(jnp.int32)
-        d = jnp.where(step_ids >= n, big, d)
-        cd = jnp.concatenate([best_d, d], axis=-1)
-        ci = jnp.concatenate([best_i, step_ids], axis=-1)
-        nd, sel = jax.lax.top_k(-cd, k)
-        return (-nd, jnp.take_along_axis(ci, sel, axis=-1)), None
-
-    init = (jnp.full((q, k), big, jnp.int32), jnp.full((q, k), n, jnp.int32))
-    (best_d, best_i), _ = jax.lax.scan(body, init, ids_steps)
-    best_i = jnp.where(best_d >= big, -1, best_i)
-    return best_d, best_i
-
-
 def stage_rerank(
     cfg, dataset: jax.Array, queries: jax.Array, ids: jax.Array,
-    impl: Optional[str] = None, k: Optional[int] = None,
+    k: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Exact-rerank stage; dispatches on ``cfg.rerank_impl``.
+    """Exact-rerank stage: gather + L1 + top-k (``kops.fused_rerank``).
 
-    'fused' (default): the fused gather+L1+running-top-k kernel — dedups
-    internally, so it accepts the raw (non-deduplicated) candidate gather.
-    'scan': the legacy chunked scan + lax.top_k — requires deduplicated ids.
-    Both return identical bits (the ``k`` (default ``cfg.k``)
-    lex-(dist, id)-smallest unique candidates, ascending; invalid ->
-    (BIG_DIST, -1)).
+    Accepts the raw candidate gather, duplicates included.  Returns the
+    ``k`` (default ``cfg.k``) lex-(dist, id)-smallest unique candidates,
+    ascending; invalid -> (BIG_DIST, -1).
     """
-    impl = impl or getattr(cfg, "rerank_impl", "fused")
     k = cfg.k if k is None else k
     if dataset.shape[0] == 0:
-        # No rows to rank against; both executors would gather from a
-        # zero-length dataset.  Emit the all-invalid result directly.
+        # No rows to rank against; the gather would read a zero-length
+        # dataset.  Emit the all-invalid result directly.
         q = ids.shape[0]
         return (jnp.full((q, k), BIG_DIST, jnp.int32),
                 jnp.full((q, k), -1, jnp.int32))
-    if impl == "scan":
-        return l1_distance_chunked(
-            dataset, queries, ids, k, cfg.rerank_chunk)
-    if impl != "fused":
-        raise ValueError(f"unknown rerank_impl: {impl!r}")
     return kops.fused_rerank(
         dataset, queries, ids, k, chunk=cfg.rerank_chunk)
 
@@ -574,10 +399,10 @@ def rerank_candidates(
 ) -> Tuple[jax.Array, jax.Array]:
     """rerank at k + T -> gid map -> tombstone -> first k (DESIGN.md §3.1).
 
-    The one tail of every path that applies tombstones (``segments``:
-    ``_query_segment``, ``_finish_segment``, ``_query_delta``).  ids (Q,
-    Ctot) local candidate ids, sentinel n (deduplicated for the 'scan'
-    rerank); gids (n,) the global id of each local row.  Returns
+    The one tail of every query (``index.finish_query``, which the flat,
+    segmented and sharded paths share, and ``segments._query_delta``).
+    ids (Q, Ctot) local candidate ids, sentinel n, duplicates allowed;
+    gids (n,) the global id of each local row.  Returns
     (dists, gids) (Q, k), lex-(dist, local id) ascending, invalid ->
     (BIG_DIST, -1): the same bits as masking dead candidates out of the
     slab before a rerank at k.
@@ -596,23 +421,15 @@ def rerank_candidates(
 
 def stage_merge_pair(
     da: jax.Array, ia: jax.Array, db: jax.Array, ib: jax.Array,
-    use_kernel: bool = True,
 ) -> Tuple[jax.Array, jax.Array]:
     """Merge two ascending (Q, k) top-k lists into one.
 
-    Invalid entries must carry dist >= BIG_DIST (id -1 or sentinel).  With
-    ``use_kernel`` the merge runs ``kops.topk_merge`` (the same op the
-    distributed ring merge uses), whose executor is chosen per backend
-    (``kops.executors``: the bitonic Pallas kernel off TPU, the
-    lexicographic sort merge on TPU); without it, the lexicographic concat
-    sort.  All of them tie-break on (dist, id), so they return identical
-    ids even on tied distances.
+    Invalid entries must carry dist >= BIG_DIST (id -1 or sentinel).  Runs
+    ``kops.topk_merge``, the op the distributed ring and tree merges use;
+    it tie-breaks on (dist, id), as ``stage_merge_concat`` does, so the two
+    return identical ids even on tied distances.
     """
-    if use_kernel:
-        return kops.topk_merge(da, ia, db, ib)
-    return stage_merge_concat(jnp.concatenate([da, db], axis=-1),
-                              jnp.concatenate([ia, ib], axis=-1),
-                              da.shape[-1])
+    return kops.topk_merge(da, ia, db, ib)
 
 
 def stage_merge_concat(
@@ -627,6 +444,6 @@ def stage_merge_concat(
     """
     # Variadic 2-key sort is the slow comparator path on XLA CPU, but R*k
     # rows are tiny (<= ~1k) and ids here are arbitrary gids, which rules
-    # out the int32 (dist, position) key packing fused_rerank_xla uses.
+    # out the int32 (dist, position) key packing the rerank uses.
     sd, si = jax.lax.sort((ds, is_), dimension=-1, num_keys=2)
     return sd[:, :k], si[:, :k]

@@ -41,8 +41,8 @@ import numpy as np
 
 from . import hashes as hashes_lib
 from . import pipeline as pipe
-from .index import (IndexConfig, IndexState, build_index, make_params,
-                    make_template, probe_index)
+from .index import (IndexConfig, IndexState, build_index, finish_query,
+                    make_params, make_template, probe_index)
 from repro.kernels.rows import widen_rows
 from repro.obs import trace as obs_trace
 
@@ -93,30 +93,8 @@ def _seg_ctot_cap(cfg: IndexConfig, state: IndexState) -> int:
             * min(cfg.candidate_cap, occ))
 
 
-@partial(jax.jit, static_argnums=0)
-def _query_segment(cfg: IndexConfig, state: IndexState, gids: jax.Array,
-                   tombstones: jax.Array, queries: jax.Array):
-    """Full pipeline over one segment: probe -> rerank at k + T -> gid map
-    -> tombstone -> first k (``pipe.rerank_candidates``).
-
-    Under the default ``cfg.rerank_impl='fused'`` the candidate list is NOT
-    pre-deduplicated (``probe_candidates`` skips the sorting dedup; the
-    fused rerank kernel masks duplicates in-kernel — DESIGN.md §Perf).
-    Local-to-gid mapping is monotone (gids ascend with local rows in every
-    segment), so the per-segment top-k stays lex-(dist, gid) ascending —
-    the invariant the bitonic ``topk_merge`` fold relies on.
-    """
-    n = state.dataset.shape[0]
-    ids = pipe.probe_candidates(
-        cfg, state.params, state.template, state.sorted_keys,
-        state.sorted_ids, n, queries)
-    return pipe.rerank_candidates(cfg, state.dataset, queries, ids, gids,
-                                  tombstones)
-
-
-# Compaction phase A over one segment == the flat index's phase A (a
-# segment IS an IndexState); one composition, so the flat and segmented
-# compact paths cannot drift.
+# Phase A over one segment is the flat index's phase A (a segment IS an
+# IndexState), so the flat and segmented paths cannot drift.
 _probe_segment = probe_index
 
 
@@ -138,27 +116,17 @@ def _finish_segment(cfg: IndexConfig, cbucket: int, c_cap: Optional[int],
                     state: IndexState, gids: jax.Array, tombstones: jax.Array,
                     probe_keys: jax.Array, lo: jax.Array, occ: jax.Array,
                     queries: jax.Array):
-    """Compaction phase B: compacted gather at the (static) rung
-    -> [dedup ->] rerank at k + T -> gid map -> tombstone -> first k.
-    The tombstones are checked on the k + T selected candidates, not on
-    every slot of the rung (``pipe.rerank_candidates``).  Same stages as
-    ``_query_segment``, so results are bit-identical at any non-truncating
-    ``cbucket`` with ``c_cap=None`` — only the padding lanes the rerank
-    pays for shrink.  An int ``c_cap`` is the two-level truncate rung's
+    """Phase B over one segment (``index.finish_query``): compacted gather
+    at the (static) rung -> rerank at k + T -> gid map -> tombstone ->
+    first k.  The tombstones are checked on the k + T selected candidates,
+    not on every slot of the rung (``pipe.rerank_candidates``).  Results
+    are those of the full slab at any non-truncating ``cbucket`` with
+    ``c_cap=None``; an int ``c_cap`` is the two-level truncate rung's
     tighter per-bucket cap (deterministic sorted-prefix truncation,
     DESIGN.md §9).
     """
-    n = state.dataset.shape[0]
-    # The scopes name this program's device ops in a profiler trace.
-    with jax.named_scope("compact_gather"):
-        ids, _ = pipe.stage_fused_probe(
-            cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
-            extents=(lo, occ), c_cap=c_cap)
-    if not pipe.rerank_handles_duplicates(cfg):
-        with jax.named_scope("dedup"):
-            ids = pipe.stage_dedup(ids, n)
-    return pipe.rerank_candidates(cfg, state.dataset, queries, ids, gids,
-                                  tombstones)
+    return finish_query(cfg, cbucket, c_cap, state, gids, tombstones,
+                        probe_keys, lo, occ, queries)
 
 
 @partial(jax.jit, static_argnums=0)
@@ -424,13 +392,14 @@ class SegmentedIndex:
         (per-segment sizes, delta-scan active, tombstone-array capacity) —
         the serving engine keys its compiled-executable bookkeeping on this
         (DESIGN.md §Perf).  Owned here so the tombstone pow2 padding policy
-        (``_tombstone_array``) and the delta-scan condition (``query``) stay
-        in one module.
+        (``_tombstone_array``) and the delta-scan condition
+        (``query_compact``) stay in one module.
         """
         tomb = len(self._tombstones)
         tomb_cap = 1 << (tomb - 1).bit_length() if tomb else 1
         return (tuple(s.size for s in self.segments),
-                self._delta_count > 0 or not self.segments, tomb_cap)
+                self._delta_count > 0
+                or not any(s.size for s in self.segments), tomb_cap)
 
     def _tombstone_array(self) -> jax.Array:
         """Ascending device array padded to a power of two with INT32_MAX.
@@ -458,33 +427,7 @@ class SegmentedIndex:
                                  jnp.asarray(self._delta_gids.copy()))
         return self._delta_cache
 
-    def query(self, queries: jax.Array, use_merge_kernel: bool = True,
-              ) -> Tuple[jax.Array, jax.Array]:
-        """Probe every segment + scan the delta; fold per-source top-k lists.
-
-        Returns (dists (Q, k) int32 ascending, gids (Q, k) int32, -1 pad).
-        Each source contributes its own candidate_cap per probed bucket, so
-        a fragmented index examines a superset of the compacted index's
-        candidates — distances can only improve until compaction.
-        """
-        queries = jnp.asarray(queries)
-        tomb = self._tombstone_array()
-        results = []
-        for seg in self.segments:
-            results.append(_query_segment(
-                self.cfg, seg.state, seg.gids, tomb, queries))
-        if self._delta_count or not results:
-            delta_pts, delta_gids = self._delta_arrays()
-            results.append(_query_delta(
-                self.cfg, delta_pts, delta_gids,
-                jnp.int32(self._delta_count), tomb, queries))
-        d, i = results[0]
-        for dn, in_ in results[1:]:
-            d, i = pipe.stage_merge_pair(d, i, dn, in_,
-                                         use_kernel=use_merge_kernel)
-        return d, i
-
-    # -- compacted query (DESIGN.md §8, two-level §9) ----------------------
+    # -- rung caps (DESIGN.md §8, two-level §9) ---------------------------
 
     def _ensure_caps(self, seg: Segment) -> None:
         """Derive the segment's two-level caps (lazy; once per seal).
@@ -503,8 +446,8 @@ class SegmentedIndex:
         would drag ``ctot_norm`` right back to the worst case, which is
         the exact failure this PR removes.  Queries past the p90 land on
         the overflow rung, which is that rung's whole job.
-        Derivation is lazy (first compact query / warmup), so indexes that
-        never use the compact path pay nothing.
+        Derivation is lazy (first query / warmup), so an index pays for it
+        only once it serves.
         """
         if seg.ctot_norm or seg.size == 0:
             return
@@ -573,11 +516,11 @@ class SegmentedIndex:
         Each ladder is a tuple of ``(cbucket, c_cap or None)`` rungs
         (``pipe.rung_ladder``): pow-2 normal rungs up to the segment's
         ``ctot_norm`` plus one overflow rung per ``overflow`` policy.
-        Zero-point segments have no probe front-end and get an empty
-        ladder.  The engine pre-compiles the gather phase at every rung
-        (warmup's (batch-bucket x rung) grid) — two-level shrinks this
-        grid, since the pow-2 rungs between ``ctot_norm`` and the
-        worst-case ``ctot_cap`` no longer exist.
+        Zero-point segments have no candidates and get an empty ladder.
+        The engine pre-compiles phase B at every rung (warmup's
+        (batch-bucket x rung) grid) — two-level shrinks this grid, since
+        the pow-2 rungs between ``ctot_norm`` and the worst-case
+        ``ctot_cap`` no longer exist.
         """
         ladders = []
         for seg in self.segments:
@@ -589,34 +532,38 @@ class SegmentedIndex:
                 seg.ctot_cap, floor, seg.ctot_norm, seg.c_norm, overflow))
         return tuple(ladders)
 
-    def query_compact(self, queries: jax.Array, floor: int = 64,
-                      use_merge_kernel: bool = True,
-                      overflow: str = "escalate", stats=None):
-        """``query`` with the fused+compacted probe front-end.
+    # -- query ------------------------------------------------------------
 
-        Per segment: one jitted probe phase (probe keys + extents +
-        counts), one scalar host read to pick the rung (``pipe.pick_rung``
-        — two-level, DESIGN.md §9), then the jitted gather+rerank phase at
-        that (static) rung — small/sparse segments stop paying the
-        worst-case ``L*P*C`` slab, and hot-bucket batches stop dragging
-        everyone to the worst-case rung.  Bit-identical to ``query`` on
-        the normal and ``overflow='escalate'`` paths (the oracle pins it);
-        ``overflow='truncate'`` bounds the overflow rung by per-bucket
-        prefix truncation instead.  Returns (dists, gids, used) where
-        ``used`` is a tuple of (segment_size, cbucket, c_cap or None)
-        triples — the shapes this call specialized on, for the engine's
-        honest cold-hit tracking.  ``stats``, when a dict, accumulates
-        ``overflow_hits`` and (truncate only) ``truncated_candidates``.
+    def query_compact(self, queries: jax.Array, floor: int = 64,
+                      overflow: str = "escalate", stats=None):
+        """Probe every segment, scan the delta, fold the per-source top-k.
+
+        Per segment: one jitted phase A (probe keys + extents + counts),
+        one scalar host read to pick the rung (``pipe.pick_rung`` —
+        two-level, DESIGN.md §9), then the jitted phase B at that (static)
+        rung — small/sparse segments stop paying the worst-case ``L*P*C``
+        slab, and hot-bucket batches stop dragging everyone to the
+        worst-case rung.  The normal and ``overflow='escalate'`` rungs give
+        the bits of the full slab (the oracle pins it); ``overflow=
+        'truncate'`` bounds the overflow rung by per-bucket prefix
+        truncation instead.  A segment with no rows has no candidates and
+        is skipped; with no rows anywhere the delta scan answers all-invalid.
+        Each source contributes its own candidate_cap per probed bucket, so
+        a fragmented index examines a superset of the compacted index's
+        candidates — distances can only improve until compaction.
+
+        Returns (dists (Q, k) int32 ascending, gids (Q, k) int32, -1 pad,
+        used) where ``used`` is a tuple of (segment_size, cbucket, c_cap or
+        None) triples — the shapes this call specialized on, for the
+        engine's honest cold-hit tracking.  ``stats``, when a dict,
+        accumulates ``overflow_hits`` and (truncate only)
+        ``truncated_candidates``.
         """
         queries = jnp.asarray(queries)
         tomb = self._tombstone_array()
         results, used = [], []
         for seg in self.segments:
             if seg.size == 0:
-                # no probe front-end to compact; the stock path already
-                # short-circuits to the all-invalid result
-                results.append(_query_segment(
-                    self.cfg, seg.state, seg.gids, tomb, queries))
                 continue
             self._ensure_caps(seg)
             with obs_trace.span("phase_a", segment=int(seg.size)):
@@ -657,17 +604,16 @@ class SegmentedIndex:
         with obs_trace.span("merge", parts=len(results)):
             d, i = results[0]
             for dn, in_ in results[1:]:
-                d, i = pipe.stage_merge_pair(d, i, dn, in_,
-                                             use_kernel=use_merge_kernel)
+                d, i = pipe.stage_merge_pair(d, i, dn, in_)
         return d, i, tuple(used)
 
     def warm_compact(self, queries: jax.Array, floor: int = 64,
                      overflow: str = "escalate"):
-        """Compile the compacted query path for this batch shape.
+        """Compile ``query_compact`` for this batch shape.
 
-        Runs the probe phase once per segment and the gather phase at
-        EVERY ladder rung (not just the rung this batch would pick), plus
-        one full ``query_compact`` for the delta/merge executables —
+        Runs phase A once per segment and phase B at EVERY ladder rung
+        (not just the rung this batch would pick), plus one full
+        ``query_compact`` for the delta/merge executables —
         live traffic on any rung then hits compiled code
         (``pipe.pick_rung`` only ever returns ladder members).  Returns
         every (segment_size, cbucket, c_cap) triple compiled.

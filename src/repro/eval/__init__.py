@@ -8,7 +8,8 @@ package measures that axis:
     shared exact ground truth, ``num_tables`` x ``num_probes`` sweeps,
     recall@k / overall-ratio curves, the derived "tables needed to hit
     recall R" statistic, and the cross-layer consistency oracle
-    (``query_index`` vs ``SegmentedIndex.query`` vs ``dist_query_fn``).
+    (``query_index`` vs ``SegmentedIndex.query_compact`` vs
+    ``dist_query_fn``).
   * ``autotune`` — the recall-target autotuner: the analytical success
     model of ``core.multiprobe`` inverted into a (L, T, candidate_cap)
     proposal, validated on a calibration split.  ``ServeConfig.target_recall``

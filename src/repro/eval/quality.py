@@ -12,7 +12,7 @@ under a shared exact-GT protocol):
     statistic: **tables needed to reach recall R** per scheme, plus the
     CP/MP table-count ratio;
   * doubles as a **cross-layer consistency oracle**: the same config is
-    pushed through ``query_index`` (flat), ``SegmentedIndex.query``
+    pushed through ``query_index`` (flat), ``SegmentedIndex.query_compact``
     (fresh, mutated, and mutated-then-compacted), the ``dist_query_fn``
     all-gather path, and the sharded+replicated ``ClusterRouter``
     (including after a replica kill + WAL-replay recovery), asserting the
@@ -36,7 +36,7 @@ import numpy as np
 from repro.core import baselines as bl
 from repro.core import pipeline as pipe
 from repro.core.index import (IndexConfig, build_index, make_params,
-                              query_index, query_index_compact)
+                              probe_index, query_index)
 from repro.core.segments import SegmentedIndex
 
 __all__ = ["SCHEMES", "QualitySpec", "QualityRun", "tables_needed"]
@@ -129,7 +129,7 @@ class QualityRun:
 
     def query_segmented(self, cfg: IndexConfig):
         idx = SegmentedIndex.from_dataset(cfg, self.key, self.data)
-        return idx.query(self.queries)
+        return idx.query_compact(self.queries)[:2]
 
     def query_dist(self, cfg: IndexConfig, merge: str = "allgather"):
         """All-gather shard_map path on a (1, n_devices) mesh.
@@ -265,15 +265,15 @@ class QualityRun:
             cfg, self.key, jnp.asarray(data_np[:n0]),
             delta_cap=delta_cap or max(64, (n - n0) // 3))
         frag.insert(data_np[n0:])                  # seals segments + delta
-        md, mi = frag.query(self.queries)
+        md, mi, _ = frag.query_compact(self.queries)
         mutated = self._score(md, mi)
         segments_while_fragmented = frag.num_segments
         frag.compact()
-        cd, ci = frag.query(self.queries)
+        cd, ci, _ = frag.query_compact(self.queries)
         compacted = self._score(cd, ci)
 
         idx = SegmentedIndex.from_dataset(cfg, self.key, self.data)
-        sd, si = idx.query(self.queries)
+        sd, si, _ = idx.query_compact(self.queries)
         return {
             "fresh_recall": fresh["recall"],
             "mutated_recall": mutated["recall"],
@@ -362,22 +362,16 @@ class QualityRun:
         }
 
     def check_compact(self, cfg: IndexConfig, flat=None) -> dict:
-        """Compacted-front-end oracle (DESIGN.md §8): the fused probe with
-        pow-2 candidate-count buckets — both the flat two-phase
-        ``query_index_compact`` and the segmented ``query_compact`` —
-        must reproduce the flat worst-case-slab result bit-for-bit, while
+        """Candidate-rung oracle (DESIGN.md §8): the segmented
+        ``query_compact``, phase B at the pow-2 candidate rung its counts
+        pick, must reproduce the flat full-slab result bit-for-bit, while
         actually shrinking the slab (the reported buckets show by how
         much)."""
         fd, fi = self.query_flat(cfg) if flat is None else flat
         fd, fi = np.asarray(fd), np.asarray(fi)
-        state = build_index(cfg, self.key, self.data)
-        cd, ci = query_index_compact(cfg, state, self.queries)
         idx = SegmentedIndex.from_dataset(cfg, self.key, self.data)
         sd, si, used = idx.query_compact(self.queries)
         return {
-            "compact_flat_matches_flat": bool(
-                np.array_equal(np.asarray(cd), fd)
-                and np.array_equal(np.asarray(ci), fi)),
             "compact_segmented_matches_flat": bool(
                 np.array_equal(np.asarray(sd), fd)
                 and np.array_equal(np.asarray(si), fi)),
@@ -420,7 +414,6 @@ class QualityRun:
         # derivation as SegmentedIndex._ensure_caps (per-bucket cap tames
         # depth outliers, p90 tames breadth outliers; the overflow rung
         # absorbs the tail past both)
-        from repro.core.index import probe_index
         sample = self.data[:: max(1, self.data.shape[0] // 64)][:64]
         _, _, occ, _ = probe_index(cfg, state, jnp.asarray(sample,
                                                           jnp.int32))
@@ -429,12 +422,15 @@ class QualityRun:
         ctot_norm = min(lp * c_norm,
                         1 << max(0, 2 * realized - 1).bit_length())
         ctot_norm = max(1, min(ctot_norm, ctot_cap))
-        ed, ei = query_index_compact(
-            cfg, state, self.queries, floor=floor, ctot_cap=ctot_cap,
-            ctot_norm=ctot_norm, c_cap=c_norm, overflow="escalate")
-        td, ti = query_index_compact(
-            cfg, state, self.queries, floor=floor, ctot_cap=ctot_cap,
-            ctot_norm=ctot_norm, c_cap=c_norm, overflow="truncate")
+        n = int(self.data.shape[0])
+        idx = SegmentedIndex.from_checkpoint(
+            cfg, state, jnp.arange(n, dtype=jnp.int32), n)
+        seg = idx.segments[0]
+        seg.ctot_cap, seg.ctot_norm, seg.c_norm = ctot_cap, ctot_norm, c_norm
+        ed, ei, _ = idx.query_compact(self.queries, floor=floor,
+                                      overflow="escalate")
+        td, ti, _ = idx.query_compact(self.queries, floor=floor,
+                                      overflow="truncate")
         uncapped = self._score(fd, fi)
         capped = self._score(np.asarray(td), np.asarray(ti))
         drop = uncapped["recall"] - capped["recall"]

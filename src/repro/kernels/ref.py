@@ -1,7 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the ``ref.py`` contract).
+"""Pure-jnp oracles for every kernel (the ``ref.py`` contract).
 
 Each function is the semantic ground truth the kernels are tested against
-(tests/test_kernels.py sweeps shapes/dtypes and asserts allclose).
+(tests/test_kernels.py, test_fused_probe.py and test_fused_rerank.py sweep
+shapes and dtypes).
 """
 from __future__ import annotations
 
@@ -52,10 +53,10 @@ def fused_rerank(dataset: jax.Array, queries: jax.Array, ids: jax.Array,
 
     Returns the k lexicographically-(dist, id)-smallest pairs over the
     *unique* valid candidate ids (slots < 0 or >= n invalid), ascending;
-    invalid slots carry (INT32_MAX // 2, -1).  This is also exactly what the
-    legacy sort-dedup + chunked-scan + lax.top_k path computes (duplicates
-    tie with themselves, and top_k's positional tie-break over an
-    id-ascending candidate list is the (dist, id) order).
+    invalid slots carry (INT32_MAX // 2, -1).  This is also exactly what a
+    sort-dedup + chunked-scan + lax.top_k computes (duplicates tie with
+    themselves, and top_k's positional tie-break over an id-ascending
+    candidate list is the (dist, id) order).
     """
     n = dataset.shape[0]
     q = ids.shape[0]
@@ -86,11 +87,11 @@ def fused_rerank(dataset: jax.Array, queries: jax.Array, ids: jax.Array,
 
 def fused_probe(sorted_keys: jax.Array, sorted_ids: jax.Array,
                 probe_keys: jax.Array, cap: int, cbucket: int):
-    """Semantic ground truth for the fused probe front-end (§8).
+    """Semantic ground truth for the probe front-end (§8).
 
-    Materializes the full staged ``(Q, L*P*C)`` slab exactly like
-    ``pipeline.stage_candidate_gather`` (the thing the fused kernel avoids),
-    then compacts it with a stable sort on the invalid flag — valid
+    Materializes the full worst-case ``(Q, L*P*C)`` slab (one slot per
+    (table, probe, offset) under the cap — the thing the compacted gather
+    avoids), then compacts it with a stable sort on the invalid flag — valid
     candidates packed to the front in their original (table, probe, offset)
     order, sentinel ``n`` tail, truncated at ``cbucket``.  Returns
     (ids (Q, cbucket) int32, counts (Q,) int32 — pre-truncation totals).
@@ -129,7 +130,7 @@ def topk_merge(da: jax.Array, ia: jax.Array, db: jax.Array, ib: jax.Array):
 
     da, db : (Q, k) distances sorted ascending; ia, ib: matching ids.
     Returns (d, i) of the k smallest of the union, ascending —
-    lexicographic on (dist, id) like the Pallas kernel, so ties resolve
+    lexicographic on (dist, id) like ``kops.topk_merge``, so ties resolve
     identically on every backend.
     """
     k = da.shape[-1]

@@ -3,10 +3,12 @@
 Layout (DESIGN.md Sect. 4):
   * dataset rows sharded over the data axes ('pod','data') -> R row shards;
   * query batch sharded over 'model'                        -> 16 query shards;
-  * every device probes its row shard for its query sub-batch;
+  * every device runs the single-shard query (phase A and phase B of
+    ``core.index``, at a static slab width) on its row shard for its
+    query sub-batch;
   * per-shard top-k results are merged across row shards either by
-    all-gather + local top-k (baseline) or by a ring of collective-permutes
-    with the bitonic topk_merge kernel (optimized — §Perf).
+    all-gather + local top-k (baseline) or by a ring or tree of
+    collective-permutes with the ``topk_merge`` op (optimized — §Perf).
 
 Hash params/walks are replicated (they are the paper's "fixed cost",
 Sect. 3.2, ~MBs) so every shard buckets identically.
@@ -24,7 +26,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import hashes as hashes_lib
 from repro.core import pipeline as pipe
-from repro.core.index import IndexConfig, IndexState, build_index, make_template
+from repro.core.index import (IndexConfig, IndexState, build_index,
+                              finish_query, make_template, no_tombstones,
+                              probe_index, row_gids)
+from repro.kernels import ops as kops
 
 __all__ = ["dist_build_fn", "dist_query_fn", "state_specs"]
 
@@ -107,9 +112,11 @@ def dist_query_fn(cfg: IndexConfig, mesh: Mesh, merge: str = "allgather",
     """Returns query(state, queries) -> (dists (Q, k), ids (Q, k)).
 
     queries: (Q_global, m) sharded over 'model'.  merge: 'allgather' | 'ring'.
-    ``cand_bucket`` statically compacts each shard's candidate slab to that
-    width via the fused probe front-end (DESIGN.md §8) — shard_map bodies
-    cannot take the two-phase host round-trip, but a caller that knows its
+    Each shard runs ``probe_index`` and ``finish_query``, the single-shard
+    query's two phases, in one program at a static slab: the full
+    ``L*P*C`` by default.  ``cand_bucket`` statically compacts each shard's
+    candidate slab to that width (DESIGN.md §8) — shard_map bodies cannot
+    take the two-phase host round-trip, but a caller that knows its
     shard occupancy (e.g. ``pipe.oracle_candidate_cap``-derived) passes the
     bound here and every shard gathers/reranks at it instead of the
     worst-case ``L*P*C``.  Results are bit-identical as long as the bucket
@@ -123,29 +130,25 @@ def dist_query_fn(cfg: IndexConfig, mesh: Mesh, merge: str = "allgather",
     rows = _row_axes(mesh)
     nshards = int(np.prod([mesh.shape[a] for a in rows]))
     k = cfg.k
-    big = jnp.int32(pipe.BIG_DIST)
 
-    def local_query(sorted_keys, sorted_ids, dataset, row_offset,
+    def local_query(sorted_keys, sorted_ids, dataset, row_offset, occ_from,
                     params, template, queries):
-        # Same staged pipeline as the single-shard path, applied to the
-        # shard's raw slices (no IndexState round-trip inside shard_map).
-        # stage_rerank dispatches per cfg.rerank_impl (fused kernel by
-        # default, DESIGN.md §Perf); adding row_offset preserves the
-        # lex-(dist, id) ascending order the ring/tree merges require.
-        n = dataset.shape[0]
-        ids = pipe.probe_candidates(
-            cfg, params, template, sorted_keys, sorted_ids, n, queries,
-            cbucket=cand_bucket, c_cap=cand_cap)
-        d, i = pipe.stage_rerank(cfg, dataset, queries, ids)   # local top-k
-        i = jnp.where(i >= 0, i + row_offset[0], -1)           # global ids
-        d = jnp.where(i < 0, big, d)
+        # The single-shard query over the shard's slices: phase A, then
+        # phase B at the static slab, rows mapped to global ids.
+        state = IndexState(params=params, sorted_keys=sorted_keys,
+                           sorted_ids=sorted_ids, dataset=dataset,
+                           template=template, row_offset=row_offset[0],
+                           occ_from=occ_from)
+        probe_keys, lo, occ, _ = probe_index(cfg, state, queries)
+        d, i = finish_query(cfg, cand_bucket, cand_cap, state,
+                            row_gids(state), no_tombstones(), probe_keys,
+                            lo, occ, queries)                  # local top-k
         if merge == "allgather":
             dg = jax.lax.all_gather(d, rows)               # (R, Qloc, k)
             ig = jax.lax.all_gather(i, rows)
             dg = jnp.moveaxis(dg, 0, 1).reshape(d.shape[0], nshards * k)
             ig = jnp.moveaxis(ig, 0, 1).reshape(d.shape[0], nshards * k)
             return pipe.stage_merge_concat(dg, ig, k)
-        from repro.kernels import ops as kops
         size = nshards
         if merge == "ring":
             # R-1 collective-permute steps; each shard's original list
@@ -173,7 +176,7 @@ def dist_query_fn(cfg: IndexConfig, mesh: Mesh, merge: str = "allgather",
         return acc_d, acc_i
 
     in_specs = (
-        P(None, rows), P(None, rows), P(rows, None), P(rows),
+        P(None, rows), P(None, rows), P(rows, None), P(rows), P(None, rows),
         P(), P(), P("model", None),
     )
     fn = shard_map(local_query, mesh=mesh, in_specs=in_specs,
@@ -182,6 +185,7 @@ def dist_query_fn(cfg: IndexConfig, mesh: Mesh, merge: str = "allgather",
 
     def query(state: IndexState, queries):
         return fn(state.sorted_keys, state.sorted_ids, state.dataset,
-                  state.row_offset, state.params, state.template, queries)
+                  state.row_offset, state.occ_from, state.params,
+                  state.template, queries)
 
     return query

@@ -15,9 +15,9 @@ Production posture on a single process:
     Single-process it runs opportunistically between batches; the
     multi-replica deployment runs it on the background thread pool
     (DESIGN.md Sect. 3);
-  * queries probe every segment with the staged pipeline and fold the
-    per-segment top-k lists with the same ``topk_merge`` op the
-    distributed ring merge uses (executor per backend, DESIGN.md §5.4);
+  * queries run phase A, a candidate rung and phase B on every segment
+    (DESIGN.md §8) and fold the per-segment top-k lists with the same
+    ``topk_merge`` op the distributed ring merge uses;
   * per-batch deadline timing + straggler hedging hook: if a batch misses
     the hedge deadline the event is recorded in ``stats['hedges']``; the
     cluster runtime (``repro.cluster``, DESIGN.md §7) turns this into a real
@@ -180,12 +180,9 @@ def compilation_cache_stats() -> dict:
 class ServeConfig:
     batch_size: int = 64           # max queries per dispatch (largest bucket)
     bucket_min: int = 8            # smallest padded batch shape
-    shape_buckets: bool = True     # pow2 buckets; False = always pad to batch_size
     warm_buckets: bool = True      # pre-compile every bucket at startup
-    compact_probe: bool = True     # fused probe front-end + pow2 candidate
-                                   # buckets (DESIGN.md §8); False = the
-                                   # worst-case L*P*C slab every batch
     cand_bucket_min: int = 128     # smallest candidate-count bucket
+                                   # (DESIGN.md §8)
     cand_cap_quantile: float = 0.999  # occupancy-histogram quantile for the
                                    # two-level per-bucket cap (DESIGN.md §9);
                                    # >= 1.0 disables the second level
@@ -201,7 +198,6 @@ class ServeConfig:
                                    # restarts read executables off disk
                                    # (directory: enable_compilation_cache)
     hedge_ms: float = 50.0
-    max_wait_ms: float = 2.0
     delta_cap: int = 1024          # delta-buffer capacity (points)
     compact_watermark: float = 0.5  # delta fill fraction that triggers compaction
     max_segments: int = 4           # segment count that triggers compaction
@@ -219,8 +215,6 @@ def shape_buckets(serve_cfg: ServeConfig) -> List[int]:
     must live router-side (pad once, fan out) even when every engine lives
     in another process.
     """
-    if not serve_cfg.shape_buckets:
-        return [serve_cfg.batch_size]
     out, b = [], max(1, serve_cfg.bucket_min)
     while b < serve_cfg.batch_size:
         out.append(b)
@@ -377,11 +371,10 @@ class AnnServingEngine:
     def warmup(self) -> None:
         """Compile every bucket shape against the current index structure.
 
-        With ``compact_probe`` this is the **(batch-bucket x
-        candidate-bucket) grid**: per batch bucket, the probe phase plus
-        the gather+rerank phase at every rung of every segment's candidate
-        ladder (DESIGN.md §8) — whichever candidate bucket live counts pick,
-        the executable is already compiled.  After this, mixed live traffic
+        This is the **(batch-bucket x candidate-bucket) grid**: per batch
+        bucket, phase A plus phase B at every rung of every segment's
+        candidate ladder (DESIGN.md §8) — whichever candidate bucket live
+        counts pick, the executable is already compiled.  After this, mixed live traffic
         hits cached executables only (``stats['bucket_cold_hits']`` stays
         flat) — recompile-free serving.
         """
@@ -391,13 +384,10 @@ class AnnServingEngine:
             if (b, sig) in self._warm:
                 continue
             warm = jnp.zeros((b, self._dim), jnp.int32)
-            if self.serve_cfg.compact_probe:
-                for key in self.index.warm_compact(
-                        warm, floor=self.serve_cfg.cand_bucket_min,
-                        overflow=self.serve_cfg.cand_overflow):
-                    self._warm.add((b, sig) + key)
-            else:
-                self.index.query(warm)[0].block_until_ready()
+            for key in self.index.warm_compact(
+                    warm, floor=self.serve_cfg.cand_bucket_min,
+                    overflow=self.serve_cfg.cand_overflow):
+                self._warm.add((b, sig) + key)
             self._warm.add((b, sig))
         self.stats["warmup_ms"] += (time.perf_counter() - t0) * 1e3
 
@@ -516,7 +506,6 @@ class AnnServingEngine:
         index's phases, the wait for the result, its copy to the host and
         the engine's bookkeeping.
         """
-        used = ()
         obs_trace.capture_begin()
         with obs_trace.span("engine_batch", bucket=int(batch.shape[0]),
                             n_real=int(n_real)):
@@ -526,12 +515,9 @@ class AnnServingEngine:
                 self.stats["bucket_cold_hits"] += 1
                 self._warm.add(key)
             t0 = time.perf_counter()
-            if self.serve_cfg.compact_probe:
-                d, i, used = self.index.query_compact(
-                    jnp.asarray(batch), floor=self.serve_cfg.cand_bucket_min,
-                    overflow=self.serve_cfg.cand_overflow, stats=self.stats)
-            else:
-                d, i = self.index.query(jnp.asarray(batch))
+            d, i, used = self.index.query_compact(
+                jnp.asarray(batch), floor=self.serve_cfg.cand_bucket_min,
+                overflow=self.serve_cfg.cand_overflow, stats=self.stats)
             with obs_trace.span("engine.result_wait"):
                 d.block_until_ready()
             ms = (time.perf_counter() - t0) * 1e3

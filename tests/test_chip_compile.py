@@ -6,10 +6,9 @@ does not lower, a program that does not fit).  Shapes are those of
 ``chip_smoke.py``: n = 1,000,000 rows of dim 128, Q = 64, L = 8, the
 smoke's probes and candidate rungs; the build and phase B also at the
 ``bigann10m-u8`` benchmark configuration's n = 10,000,000 uint8 rows.
-Code that asks ``jax.default_backend()``
-sees the CPU here, so each compile steers the executor choice to the one
-the chip makes.  All compiles stay in this one file: only the process that
-describes the topology may load the TPU library.
+Every query stage has one executor on every backend, so what compiles here
+is what the chip runs.  All compiles stay in this one file: only the
+process that describes the topology may load the TPU library.
 """
 import dataclasses
 import json
@@ -25,7 +24,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import pipeline as pipe
 from repro.core.index import build_index, make_params, probe_index
 from repro.core.segments import _finish_segment, _query_delta
-from repro.kernels import ops as kops
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import chip_smoke as smoke  # noqa: E402
@@ -54,16 +52,6 @@ BIGANN_WORST_RUNG = (BIGANN_CFG.num_tables * BIGANN_CFG.probes_per_table
 STORES = {"1M-int32": (SHAPE, CFG), "10M-uint8": (BIGANN, BIGANN_CFG)}
 TOMBSTONES = 1 << (SHAPE.deletes - 1).bit_length()
 HBM_BYTES = 16 * 2 ** 30           # one v5e chip
-EXECUTORS = kops.executors         # the real table, before any steering
-
-
-def test_executor_choice_per_backend():
-    # the chip gets the executors these compiles prove; off the chip the
-    # merge runs the (interpreted) Pallas kernel the parity tests pin
-    assert EXECUTORS("tpu") == {"probe": "xla", "rerank": "xla",
-                                "merge": "xla"}
-    assert EXECUTORS("cpu")["merge"] == "pallas"
-    assert EXECUTORS()["merge"] == "pallas"   # this suite runs on the CPU
 
 
 @pytest.fixture(scope="module")
@@ -83,12 +71,9 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def as_chip(monkeypatch, one_chip):
-    """Executors as the chip picks them, and no persistent cache (a compile
-    for a described chip is written to it but cannot be read back)."""
-    monkeypatch.setattr(kops, "executors",
-                        lambda backend=None: EXECUTORS("tpu"))
-    monkeypatch.setattr(kops, "use_interpret", lambda: False)
+def as_chip(one_chip):
+    """The described chip, and no persistent cache (a compile for a
+    described chip is written to it but cannot be read back)."""
     jax.config.update("jax_enable_compilation_cache", False)
     jax_cc.reset_cache()
     yield one_chip
@@ -139,18 +124,34 @@ def test_compile_phase_a_probe_extents(as_chip):
     _compile(probe_index, CFG, _state(as_chip), _queries(as_chip))
 
 
+def _phase_b_args(chip, shape=SHAPE, cfg=CFG, rung=WORST_RUNG):
+    """``_finish_segment``'s arguments for one batch at ``rung``."""
+    state, q = _state(chip, shape, cfg), _queries(chip, shape)
+    probe_keys, lo, occ, _ = _on(chip, jax.eval_shape(
+        lambda s, x: probe_index(cfg, s, x), state, q))
+    return (cfg, rung, None, state, _spec_of((shape.n,), jnp.int32, chip),
+            _spec_of((TOMBSTONES,), jnp.int32, chip), probe_keys, lo, occ, q)
+
+
+@pytest.mark.parametrize("phase, module", [
+    ("a", "jit_probe_index"), ("b", "jit__finish_segment")])
+def test_query_programs_keep_their_trace_names(as_chip, phase, module):
+    """The two programs a served batch runs carry the module names the
+    benchmark's per-layer metrics read from a profiler trace."""
+    if phase == "a":
+        lowered = probe_index.lower(CFG, _state(as_chip), _queries(as_chip))
+    else:
+        lowered = _finish_segment.lower(*_phase_b_args(as_chip))
+    head = lowered.as_text().split("\n", 1)[0]
+    assert head.startswith(f"module @{module} "), head
+
+
 @pytest.mark.parametrize("store, rung", [
     ("1M-int32", smoke.CAND_BUCKET_MIN), ("1M-int32", WORST_RUNG),
     ("10M-uint8", BIGANN_WORST_RUNG)])
 def test_compile_phase_b_gather_tombstone_rerank(as_chip, store, rung):
     shape, cfg = STORES[store]
-    state, q = _state(as_chip, shape, cfg), _queries(as_chip, shape)
-    probe_keys, lo, occ, _ = _on(as_chip, jax.eval_shape(
-        lambda s, x: probe_index(cfg, s, x), state, q))
-    _compile(_finish_segment, cfg, rung, None, state,
-             _spec_of((shape.n,), jnp.int32, as_chip),
-             _spec_of((TOMBSTONES,), jnp.int32, as_chip),
-             probe_keys, lo, occ, q)
+    _compile(_finish_segment, *_phase_b_args(as_chip, shape, cfg, rung))
 
 
 def test_compile_delta_scan(as_chip):

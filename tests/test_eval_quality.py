@@ -229,7 +229,7 @@ def test_engine_target_recall_autotunes_and_reports(run):
     from repro.core.segments import SegmentedIndex
     ref = SegmentedIndex.from_dataset(eng.cfg, jax.random.PRNGKey(0),
                                       run.data)
-    rd, ri = ref.query(run.queries[:4])
+    rd, ri, _ = ref.query_compact(run.queries[:4])
     np.testing.assert_array_equal(d, np.asarray(rd))
     np.testing.assert_array_equal(i, np.asarray(ri))
 

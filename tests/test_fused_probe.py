@@ -1,17 +1,17 @@
-"""Fused probe front-end: executor parity + compaction properties (§8).
+"""Probe front-end: gather parity + compaction properties (§8).
 
 Three layers of pinning:
-  1. kernel parity — ``fused_probe_xla`` == ``fused_probe_pallas``
-     (interpret) == ``ref.fused_probe`` == a plain-python oracle, across
+  1. gather parity — ``fused_probe_xla`` == ``kops.fused_probe`` ==
+     ``ref.fused_probe`` == a plain-python oracle, across
      hypothesis-driven (Q, L, P, C, n) shapes and the named edge cases
      (empty buckets, all-sentinel queries, single-point segments,
      duplicate candidates across tables, truncating buckets);
-  2. pipeline parity — ``probe_candidates`` fused vs staged feed the rerank
-     identical candidate *sets*, so ``query_index`` is bit-identical under
-     either ``probe_impl`` and under the two-phase compacted path;
-  3. serving parity — the engine's compacted path returns the same bits as
-     the worst-case-slab path, with zero unplanned recompiles after the
-     (batch-bucket x candidate-bucket) warmup grid.
+  2. pipeline parity — phase A + the gather feed the rerank the oracle's
+     candidate set, the full-slab ``query_index`` equals the frozen seed
+     implementation, and every covering rung gives the full slab's bits;
+  3. serving parity — the engine returns the bits of the full-slab query,
+     with zero unplanned recompiles after the (batch-bucket x
+     candidate-bucket) warmup grid.
 """
 import dataclasses
 import math
@@ -24,14 +24,14 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import pipeline as pipe
-from repro.core.index import (IndexConfig, build_index, query_index,
-                              query_index_compact)
+from repro.core.index import (IndexConfig, build_index, probe_index,
+                              query_index)
 from repro.core.segments import SegmentedIndex
 from repro.data import ann_synthetic as ds
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.fused_probe import (compact_gather_xla, fused_probe_pallas,
-                                       fused_probe_xla)
+from repro.kernels.fused_probe import compact_gather_xla, fused_probe_xla
+from test_segments import _seed_query_index
 
 KEY = jax.random.PRNGKey(0)
 
@@ -59,8 +59,6 @@ def _assert_all_equal(keys, ids, pk, cap, cbucket):
     want_ids, want_cnt = np_fused_probe(keys, ids, pk, cap, cbucket)
     for name, got in {
         "xla": fused_probe_xla(keys_j, ids_j, pk_j, cap, cbucket),
-        "pallas": fused_probe_pallas(keys_j, ids_j, pk_j, cap, cbucket,
-                                     interpret=True),
         "ref": ref.fused_probe(keys_j, ids_j, pk_j, cap, cbucket),
         "ops": kops.fused_probe(keys_j, ids_j, pk_j, cap, cbucket),
     }.items():
@@ -71,13 +69,14 @@ def _assert_all_equal(keys, ids, pk, cap, cbucket):
 
 
 # ---------------------------------------------------------------------------
-# 1. kernel parity
+# 1. gather parity
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_fused_probe_property_parity(data):
-    """All executors agree with the python oracle on random shapes/keys."""
+    """Every gather entry point agrees with the python oracle on random
+    shapes/keys."""
     l = data.draw(st.integers(1, 5), label="L")
     n = data.draw(st.integers(0, 200), label="n")
     p = data.draw(st.integers(1, 12), label="P")
@@ -111,7 +110,7 @@ def test_tiny_segments(n):
 
 def test_all_sentinel_query_and_uint32_extremes():
     """Probe keys that match nothing -> all-sentinel row, count 0; the
-    UINT32_MAX probe key must not count the Pallas executor's pad tail."""
+    UINT32_MAX probe key, above every key, counts nothing."""
     rng = np.random.default_rng(1)
     l, n, p = 2, 150, 6
     keys = np.sort(rng.integers(10, 50, (l, n)).astype(np.uint32), axis=-1)
@@ -127,7 +126,7 @@ def test_all_sentinel_query_and_uint32_extremes():
 def test_duplicate_candidates_across_tables_survive():
     """A point present in every table's probed bucket appears once per
     (table, probe) hit — compaction must NOT dedup (the rerank owns that),
-    or the fused path would diverge from the staged slab's candidate set."""
+    or the compacted slab would diverge from the full slab's candidates."""
     l, n, p = 4, 8, 1
     keys = np.zeros((l, n), np.uint32)           # one bucket per table
     ids = np.tile(np.arange(n, dtype=np.int32), (l, 1))
@@ -337,48 +336,82 @@ def cfg():
                        candidate_cap=32, universe=64, k=8, rerank_chunk=128)
 
 
-@pytest.mark.parametrize("rerank_impl", ["fused", "scan"])
-def test_query_index_probe_impls_bit_identical(cfg, small, rerank_impl):
+def _one_segment(cfg, state):
+    """A one-segment index over ``state``, gids = rows."""
+    n = state.dataset.shape[0]
+    return SegmentedIndex.from_checkpoint(
+        cfg, state, jnp.arange(n, dtype=jnp.int32), n)
+
+
+@pytest.mark.parametrize("dataset_dtype", ["int32", "uint8"])
+def test_query_index_probe_impls_bit_identical(cfg, small, dataset_dtype):
+    """The full-slab ``query_index`` (phase A + phase B in one program)
+    equals the frozen seed implementation, whatever the rows are stored
+    as; the seed reads int32 rows."""
     data, queries = small
-    cfg = dataclasses.replace(cfg, rerank_impl=rerank_impl)
-    state = build_index(cfg, KEY, data)
-    d0, i0 = query_index(
-        dataclasses.replace(cfg, probe_impl="staged"), state, queries)
-    d1, i1 = query_index(cfg, state, queries)
-    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
-    np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+    seed = _seed_query_index(cfg, build_index(cfg, KEY, data), queries)
+    cfg_s = dataclasses.replace(cfg, dataset_dtype=dataset_dtype)
+    got = query_index(cfg_s, build_index(cfg_s, KEY, data), queries)
+    np.testing.assert_array_equal(np.asarray(seed[0]), np.asarray(got[0]))
+    np.testing.assert_array_equal(np.asarray(seed[1]), np.asarray(got[1]))
 
 
 def test_query_index_compact_bit_identical(cfg, small):
+    """Phase B at the rung the counts pick gives the full slab's bits."""
     data, queries = small
     state = build_index(cfg, KEY, data)
     d0, i0 = query_index(cfg, state, queries)
     for floor in (16, 64, 4096):   # tiny, typical, bigger-than-worst-case
-        d1, i1 = query_index_compact(cfg, state, queries, floor=floor)
+        d1, i1, used = _one_segment(cfg, state).query_compact(
+            queries, floor=floor)
         np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
         np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
 
 
 def test_probe_candidates_same_set_after_dedup(cfg, small):
+    """Phase A + the full-slab gather hand the rerank the oracle's
+    candidates: the same set per query once duplicates are dropped."""
     data, queries = small
     state = build_index(cfg, KEY, data)
     n = data.shape[0]
-    args = (state.params, state.template, state.sorted_keys,
-            state.sorted_ids, n, queries)
-    staged = pipe.probe_candidates(
-        dataclasses.replace(cfg, probe_impl="staged"), *args, dedup=True)
-    fused = pipe.probe_candidates(cfg, *args, dedup=True)
-    np.testing.assert_array_equal(np.asarray(staged), np.asarray(fused))
+    pk, lo, occ, counts = probe_index(cfg, state, queries)
+    ids, got_counts = pipe.stage_fused_probe(
+        cfg, state.sorted_keys, state.sorted_ids, pk, n,
+        extents=(lo, occ))
+    full = cfg.num_tables * cfg.probes_per_table * cfg.candidate_cap
+    want, want_counts = np_fused_probe(
+        np.asarray(state.sorted_keys), np.asarray(state.sorted_ids),
+        np.asarray(pk), cfg.candidate_cap, full)
+    np.testing.assert_array_equal(np.asarray(got_counts), want_counts)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    ids = np.asarray(ids)
+    for row, want_row in zip(ids, want):
+        np.testing.assert_array_equal(np.unique(row[row < n]),
+                                      np.unique(want_row[want_row < n]))
+    assert (want_counts > np.asarray([len(np.unique(r[r < n]))
+                                      for r in ids])).any()  # dups exist
 
 
 def test_segmented_query_compact_bit_identical(cfg, small):
+    """Through sealed segments, the delta and deletes, every segment at
+    the rung its counts pick gives the bits of every segment at its full
+    non-truncating width (a single-level ladder, floor past the top)."""
     data, queries = small
     data_np = np.asarray(data)
-    idx = SegmentedIndex.from_dataset(cfg, KEY, jnp.asarray(data_np[:1500]),
-                                      delta_cap=256)
-    idx.insert(data_np[1500:])                 # seals segments + delta
-    idx.delete([1, 2, 2000])
-    d0, i0 = idx.query(queries)
+
+    def mutated(cap_quantile):
+        idx = SegmentedIndex.from_dataset(
+            cfg, KEY, jnp.asarray(data_np[:1500]), delta_cap=256,
+            cap_quantile=cap_quantile)
+        idx.insert(data_np[1500:])             # seals segments + delta
+        idx.delete([1, 2, 2000])
+        return idx
+
+    wide = mutated(1.0)
+    d0, i0, used0 = wide.query_compact(queries, floor=1 << 30)
+    assert all(cb == seg.ctot_cap
+               for (_, cb, _), seg in zip(used0, wide.segments))
+    idx = mutated(0.999)
     d1, i1, used = idx.query_compact(queries)
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -463,7 +496,7 @@ def test_segmented_truncate_overflow_stats(cfg, small):
     assert stats["truncated_candidates"] > 0
     assert all(cb == 64 and cc == 1 for _, cb, cc in used)
     # escalate on the same forced caps falls back to the exact rung
-    d0, i0 = idx.query(queries)
+    d0, i0 = query_index(cfg, idx.segments[0].state, queries)
     d1, i1, used_e = idx.query_compact(queries, overflow="escalate")
     np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
@@ -508,18 +541,15 @@ def test_engine_compact_probe_smoke(cfg, small):
 
     data, queries = small
     qn = np.asarray(queries)
-    mk = lambda compact: AnnServingEngine(
+    eng_c = AnnServingEngine(
         cfg, ServeConfig(batch_size=8, bucket_min=2, delta_cap=64,
-                         compact_probe=compact, cand_bucket_min=64,
-                         persistent_cache=False), data)
-    eng_c, eng_f = mk(True), mk(False)
+                         cand_bucket_min=64, persistent_cache=False), data)
     cold_after_warm = eng_c.stats["bucket_cold_hits"]
-    for engine in (eng_c, eng_f):
-        engine.submit(qn[:3]); engine.submit(qn[3:])
+    eng_c.submit(qn[:3]); eng_c.submit(qn[3:])
     dc, ic = eng_c.drain()
-    df, if_ = eng_f.drain()
-    np.testing.assert_array_equal(dc, df)
-    np.testing.assert_array_equal(ic, if_)
+    df, if_ = query_index(cfg, eng_c.index.segments[0].state, queries)
+    np.testing.assert_array_equal(dc, np.asarray(df))
+    np.testing.assert_array_equal(ic, np.asarray(if_))
     # the (batch-bucket x candidate-bucket) warmup grid covered every live
     # shape: no unplanned recompiles
     assert eng_c.stats["bucket_cold_hits"] == cold_after_warm

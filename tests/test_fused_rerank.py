@@ -1,33 +1,38 @@
-"""Fused rerank parity: Pallas kernel (interpret), XLA executor, jnp oracle,
-and the legacy sort-dedup + scan + lax.top_k path must agree bit-for-bit —
-including the adversarial cases ISSUE 2 pins: all-sentinel candidate lists,
-Ctot < k, duplicate ids, tied distances, Q=1 and non-multiple-of-tile Q."""
-import dataclasses
-
+"""Rerank parity: the served rerank, the jnp oracle, and a sort-dedup +
+scan + lax.top_k must agree bit-for-bit — including the adversarial cases
+ISSUE 2 pins: all-sentinel candidate lists, Ctot < k, duplicate ids, tied
+distances, Q=1 and Q not a multiple of any tile."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import pipeline as pipe
-from repro.core.index import IndexConfig, build_index, query_index
+from repro.core.baselines import l1_distance_chunked
+from repro.core.index import IndexConfig, build_index, probe_index, query_index
 from repro.kernels import ops, ref
-from repro.kernels.fused_rerank import fused_rerank_pallas, fused_rerank_xla
+from repro.kernels.fused_rerank import fused_rerank_xla
 
 BIG = pipe.BIG_DIST
 
 
-def _all_impls(dataset, queries, ids, k, chunk=16, bq=4, bc=8, bm=128):
-    """(name, (d, i)) for every fused executor plus the legacy scan path."""
+def _dedup(ids, n):
+    """Sorted ids, invalid and repeated ones -> sentinel n: the unique
+    candidates the exact scan needs."""
+    ids = jnp.sort(jnp.where((ids < 0) | (ids > n), n, ids), axis=-1)
+    dup = jnp.concatenate([jnp.zeros((ids.shape[0], 1), bool),
+                           ids[:, 1:] == ids[:, :-1]], axis=-1)
+    return jnp.where(dup, n, ids)
+
+
+def _all_impls(dataset, queries, ids, k, chunk=16):
+    """(name, (d, i)) for the served rerank, the oracle and the scan."""
     n = dataset.shape[0]
-    legacy_ids = pipe.stage_dedup(jnp.where(ids < 0, n, ids), n)
     return [
         ("oracle", ref.fused_rerank(dataset, queries, ids, k)),
         ("xla", fused_rerank_xla(dataset, queries, ids, k, chunk=chunk)),
-        ("pallas", fused_rerank_pallas(dataset, queries, ids, k,
-                                       bq=bq, bc=bc, bm=bm, interpret=True)),
-        ("legacy_scan", pipe.l1_distance_chunked(
-            dataset, queries, legacy_ids, k, chunk)),
+        ("scan", l1_distance_chunked(
+            dataset, queries, _dedup(ids, n), k, chunk)),
     ]
 
 
@@ -68,12 +73,12 @@ def test_fused_all_sentinel_rows():
 
 
 def test_fused_duplicate_ids_take_one_slot():
-    # one point appearing in many probe slots must produce ONE result even
-    # though the fused path never runs the sorting dedup stage.
+    # one point appearing in many probe slots must produce ONE result: the
+    # rerank takes the gather's slab as it is, duplicates included.
     rng = np.random.default_rng(1)
     dataset = jnp.asarray(rng.integers(0, 50, (6, 8)).astype(np.int32))
     ids = jnp.asarray([[2, 2, 2, 2, 4, 4, 6, 6]], jnp.int32)  # 6 == sentinel
-    impls = _all_impls(dataset, dataset[:1], ids, 4, chunk=4, bc=4)
+    impls = _all_impls(dataset, dataset[:1], ids, 4, chunk=4)
     _assert_all_equal(impls)
     i = np.asarray(impls[0][1][1])[0]
     real = i[i >= 0]
@@ -88,7 +93,7 @@ def test_fused_tied_distances_deterministic():
     queries = jnp.full((2, m), 1, jnp.int32)
     ids = jnp.asarray([[9, 7, 7, 11, 3, 9, 5, 3],
                        [10, 10, 10, 10, 2, 2, 2, 2]], jnp.int32)
-    impls = _all_impls(dataset, queries, ids, k, chunk=4, bc=4)
+    impls = _all_impls(dataset, queries, ids, k, chunk=4)
     _assert_all_equal(impls)
     d, i = (np.asarray(x) for x in impls[0][1])
     np.testing.assert_array_equal(i[0], [3, 5, 7, 9, 11])
@@ -97,15 +102,15 @@ def test_fused_tied_distances_deterministic():
 
 
 def test_fused_duplicate_pressure_many_tiles():
-    # duplicates of the global best spread across MANY kernel tiles: the
-    # running-best id-keyed mask (not just within-tile masking) must fire.
+    # duplicates of the global best spread across MANY scan chunks: one
+    # copy each survives, wherever the copies fall.
     rng = np.random.default_rng(2)
     n, m, k = 64, 8, 8
     dataset = jnp.asarray(rng.integers(0, 100, (n, m)).astype(np.int32))
     queries = jnp.asarray(np.asarray(dataset[:2]))  # self-queries -> d=0 best
     ids = np.tile(np.arange(8, dtype=np.int32), (2, 16))  # every tile repeats
     ids = jnp.asarray(ids)
-    impls = _all_impls(dataset, queries, ids, k, chunk=8, bc=8)
+    impls = _all_impls(dataset, queries, ids, k, chunk=8)
     _assert_all_equal(impls)
     for row in np.asarray(impls[0][1][1]):
         real = row[row >= 0]
@@ -123,21 +128,26 @@ def test_fused_empty_dataset_and_empty_candidates():
 
 
 def test_stage_rerank_impls_bit_identical_end_to_end():
-    # whole-pipeline dispatch: cfg.rerank_impl='fused' (sort-free dedup) vs
-    # 'scan' (sort dedup + chunked top_k) must return identical bits.
+    # the rerank as the query path runs it, on the candidates phase A and
+    # the gather hand it (duplicates included), against the oracle over
+    # the same candidates; the whole query returns the same bits.
     from repro.data import ann_synthetic as ds
     spec = ds.DatasetSpec("fr", n=2000, dim=16, universe=64, num_clusters=6)
     data = jnp.asarray(ds.make_dataset(spec))
     queries = jnp.asarray(ds.make_queries(spec, np.asarray(data), 12))
-    base = IndexConfig(num_tables=4, num_hashes=8, width=24, num_probes=20,
-                       candidate_cap=16, universe=64, k=8, rerank_chunk=64,
-                       rerank_impl="fused")
-    scan = dataclasses.replace(base, rerank_impl="scan")
-    state = build_index(base, jax.random.PRNGKey(0), data)
-    fd, fi = query_index(base, state, queries)
-    sd, si = query_index(scan, state, queries)
-    np.testing.assert_array_equal(np.asarray(fd), np.asarray(sd))
-    np.testing.assert_array_equal(np.asarray(fi), np.asarray(si))
+    cfg = IndexConfig(num_tables=4, num_hashes=8, width=24, num_probes=20,
+                      candidate_cap=16, universe=64, k=8, rerank_chunk=64)
+    state = build_index(cfg, jax.random.PRNGKey(0), data)
+    pk, lo, occ, _ = probe_index(cfg, state, queries)
+    ids, _ = pipe.stage_fused_probe(cfg, state.sorted_keys, state.sorted_ids,
+                                    pk, data.shape[0], extents=(lo, occ))
+    rd, ri = ref.fused_rerank(state.dataset, queries, ids, cfg.k)
+    sd, si = pipe.stage_rerank(cfg, state.dataset, queries, ids)
+    np.testing.assert_array_equal(np.asarray(rd), np.asarray(sd))
+    np.testing.assert_array_equal(np.asarray(ri), np.asarray(si))
+    fd, fi = query_index(cfg, state, queries)
+    np.testing.assert_array_equal(np.asarray(rd), np.asarray(fd))
+    np.testing.assert_array_equal(np.asarray(ri), np.asarray(fi))
 
 
 def test_packed_key_boundary_falls_back_exactly():
@@ -167,29 +177,18 @@ def test_packed_key_boundary_falls_back_exactly():
 
 
 def test_merge_backends_agree_on_tied_ids():
-    # kernel, jnp fallback, ref oracle, and concat merge must return the
-    # SAME ids on tied distances (all lex on (dist, id) — regression for a
-    # kernel/fallback divergence caught in review).
+    # the merge op, the pipeline's pair merge, ref oracle, and concat
+    # merge must return the SAME ids on tied distances (all lex on
+    # (dist, id) — regression for a divergence caught in review).
     da = jnp.asarray([[5, 5]], jnp.int32); ia = jnp.asarray([[9, 10]], jnp.int32)
     db = jnp.asarray([[5, 5]], jnp.int32); ib = jnp.asarray([[1, 2]], jnp.int32)
     want_d, want_i = [[5, 5]], [[1, 2]]
     for name, (d, i) in [
         ("kernel", ops.topk_merge(da, ia, db, ib)),
-        ("fallback", pipe.stage_merge_pair(da, ia, db, ib, use_kernel=False)),
+        ("pair", pipe.stage_merge_pair(da, ia, db, ib)),
         ("ref", ref.topk_merge(da, ia, db, ib)),
         ("concat", pipe.stage_merge_concat(
             jnp.concatenate([da, db], -1), jnp.concatenate([ia, ib], -1), 2)),
     ]:
         np.testing.assert_array_equal(np.asarray(d), want_d, err_msg=name)
         np.testing.assert_array_equal(np.asarray(i), want_i, err_msg=name)
-
-
-def test_bitonic_sort_rows_matches_lexsort():
-    from repro.kernels.topk_merge import bitonic_sort_rows
-    rng = np.random.default_rng(3)
-    d = rng.integers(0, 7, (5, 32)).astype(np.int32)    # heavy ties
-    i = rng.integers(0, 1000, (5, 32)).astype(np.int32)
-    sd, si = bitonic_sort_rows(jnp.asarray(d), jnp.asarray(i))
-    od, oi = jax.lax.sort((jnp.asarray(d), jnp.asarray(i)), num_keys=2)
-    np.testing.assert_array_equal(np.asarray(sd), np.asarray(od))
-    np.testing.assert_array_equal(np.asarray(si), np.asarray(oi))

@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 from repro.core import baselines as bl
-from repro.core.index import (IndexConfig, build_index, query_index,
-                              _probe_candidate_ids, l1_distance_chunked)
+from repro.core import pipeline as pipe
+from repro.core.baselines import l1_distance_chunked
+from repro.core.index import (IndexConfig, build_index, probe_index,
+                              query_index)
 from repro.data import ann_synthetic as ds
 
 KEY = jax.random.PRNGKey(0)
@@ -89,8 +91,10 @@ def test_row_offset_global_ids(cfg, clustered):
 
 def test_candidate_sentinel_handling(cfg, state, clustered):
     data, queries = clustered
-    ids = _probe_candidate_ids(cfg, state, queries[:8])
+    pk, lo, occ, _ = probe_index(cfg, state, queries[:8])
     n = data.shape[0]
+    ids, _ = pipe.stage_fused_probe(cfg, state.sorted_keys, state.sorted_ids,
+                                    pk, n, extents=(lo, occ))
     a = np.asarray(ids)
     assert a.max() <= n
     # rerank with an all-sentinel row -> id -1, huge dist

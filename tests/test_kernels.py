@@ -57,7 +57,7 @@ def test_topk_merge_property(q, k, seed):
     db = np.sort(rng.integers(0, 500, (q, k)).astype(np.int32), axis=-1)
     ia = rng.integers(0, 10_000, (q, k)).astype(np.int32)
     ib = rng.integers(0, 10_000, (q, k)).astype(np.int32)
-    do, io = ops.topk_merge(*map(jnp.asarray, (da, ia, db, ib)), bq=4)
+    do, io = ops.topk_merge(*map(jnp.asarray, (da, ia, db, ib)))
     dr, _ = ref.topk_merge(*map(jnp.asarray, (da, ia, db, ib)))
     np.testing.assert_array_equal(np.asarray(do), np.asarray(dr))
     # every returned (dist) must exist in the union with right multiplicity

@@ -1,32 +1,39 @@
-"""Unit coverage for the staged pipeline: dedup sentinel path, tombstone
-masking, and the topk_merge kernel's tie / all-invalid edge cases."""
+"""Unit coverage for the query stages: duplicate candidates, tombstone
+masking, and the topk_merge op's tie / all-invalid edge cases."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import pipeline as pipe
-from repro.core.index import IndexConfig, build_index, _probe_candidate_ids
+from repro.core.index import (IndexConfig, build_index, probe_index,
+                              query_index)
 from repro.kernels import ops
 
 BIG = pipe.BIG_DIST
 
 
 # ---------------------------------------------------------------------------
-# Candidate dedup: duplicates across tables/probes -> sentinel n, never
-# reranked twice.
+# Duplicate candidates across tables/probes: the rerank takes the slab as
+# the gather hands it and ranks each point once.
 # ---------------------------------------------------------------------------
+
+RERANK_CFG = IndexConfig(k=8, rerank_chunk=4)
+
 
 def test_stage_dedup_maps_duplicates_to_sentinel():
     n = 10
+    rng = np.random.default_rng(3)
+    dataset = jnp.asarray(rng.integers(0, 50, (n, 8)), jnp.int32)
     ids = jnp.asarray([[3, 1, 3, 7, 1, 9, n, n],
                        [5, 5, 5, 5, n, n, n, n]], jnp.int32)
-    out = np.asarray(pipe.stage_dedup(ids, n))
-    # sorted ascending, each real id exactly once, the rest sentinel
-    assert sorted(out[0][out[0] < n].tolist()) == [1, 3, 7, 9]
-    assert (out[0] == n).sum() == 4
-    assert out[1][out[1] < n].tolist() == [5]
-    assert (out[1] == n).sum() == 7
+    d, i = (np.asarray(a) for a in
+            pipe.stage_rerank(RERANK_CFG, dataset, dataset[:2], ids))
+    # each real id exactly once, the rest the (BIG, -1) sentinel
+    assert sorted(i[0][i[0] >= 0].tolist()) == [1, 3, 7, 9]
+    assert (i[0] == -1).sum() == 4 and (d[0][i[0] < 0] == BIG).all()
+    assert i[1][i[1] >= 0].tolist() == [5]
+    assert (i[1] == -1).sum() == 7 and (d[1][i[1] < 0] == BIG).all()
 
 
 def test_duplicate_candidates_reranked_once():
@@ -34,8 +41,7 @@ def test_duplicate_candidates_reranked_once():
     rng = np.random.default_rng(0)
     dataset = jnp.asarray(rng.integers(0, 50, (6, 8)), jnp.int32)
     dup_ids = jnp.asarray([[2, 2, 2, 2, 4, 4, 6, 6]], jnp.int32)
-    deduped = pipe.stage_dedup(dup_ids, 6)
-    d, i = pipe.l1_distance_chunked(dataset, dataset[:1], deduped, 4, 4)
+    d, i = pipe.stage_rerank(RERANK_CFG, dataset, dataset[:1], dup_ids, k=4)
     i = np.asarray(i)[0]
     real = i[i >= 0]
     assert len(set(real.tolist())) == len(real)
@@ -44,16 +50,22 @@ def test_duplicate_candidates_reranked_once():
 
 def test_probe_candidates_unique_on_cloned_points():
     # identical points land in the same bucket of EVERY table and probe ->
-    # maximal duplication pressure on the dedup stage.
+    # maximal duplication pressure: the gather keeps every copy, the
+    # query returns each clone once.
     cfg = IndexConfig(num_tables=4, num_hashes=6, width=16, num_probes=10,
-                      candidate_cap=16, universe=32, k=4, rerank_chunk=64)
+                      candidate_cap=16, universe=32, k=5, rerank_chunk=64)
     point = (np.arange(8) * 2).astype(np.int32)
     data = jnp.asarray(np.tile(point, (5, 1)))     # 5 clones
     state = build_index(cfg, jax.random.PRNGKey(0), data)
-    ids = np.asarray(_probe_candidate_ids(cfg, state, data[:1]))[0]
+    pk, lo, occ, _ = probe_index(cfg, state, data[:1])
+    ids, _ = pipe.stage_fused_probe(cfg, state.sorted_keys, state.sorted_ids,
+                                    pk, data.shape[0], extents=(lo, occ))
+    ids = np.asarray(ids)[0]
     real = ids[ids < data.shape[0]]
-    assert len(set(real.tolist())) == len(real)
+    assert len(real) > len(set(real.tolist()))     # copies across tables
     assert set(real.tolist()) == {0, 1, 2, 3, 4}
+    _, got = query_index(cfg, state, data[:1])
+    assert sorted(np.asarray(got)[0].tolist()) == [0, 1, 2, 3, 4]
 
 
 def test_stage_tombstone_masks_deleted_gids():
@@ -144,20 +156,27 @@ def test_topk_merge_one_side_invalid():
     np.testing.assert_array_equal(np.asarray(i), ia)
 
 
-@pytest.mark.parametrize("use_kernel", [True, False])
-def test_stage_merge_pair_backends_agree_on_dists(use_kernel):
+@pytest.mark.parametrize("ties", ["few", "many"])
+def test_stage_merge_pair_backends_agree_on_dists(ties):
+    # distances from a wide range (ties rare) or a narrow one (most tie);
+    # the pair merge keeps the oracle's k smallest, ascending, and breaks
+    # ties by id like the concat merge
     rng = np.random.default_rng(1)
     k = 8
-    da = np.sort(rng.integers(0, 100, (5, k)).astype(np.int32), axis=1)
-    db = np.sort(rng.integers(0, 100, (5, k)).astype(np.int32), axis=1)
+    hi = 100 if ties == "few" else 4
+    da = np.sort(rng.integers(0, hi, (5, k)).astype(np.int32), axis=1)
+    db = np.sort(rng.integers(0, hi, (5, k)).astype(np.int32), axis=1)
     ia = rng.integers(0, 1000, (5, k)).astype(np.int32)
     ib = rng.integers(0, 1000, (5, k)).astype(np.int32)
     d, i = pipe.stage_merge_pair(jnp.asarray(da), jnp.asarray(ia),
-                                 jnp.asarray(db), jnp.asarray(ib),
-                                 use_kernel=use_kernel)
+                                 jnp.asarray(db), jnp.asarray(ib))
     od, _ = _oracle_merge(da, ia, db, ib, k)
     np.testing.assert_array_equal(np.asarray(d), od)
     assert (np.diff(np.asarray(d), axis=1) >= 0).all()
+    cd, ci = pipe.stage_merge_concat(
+        jnp.concatenate([jnp.asarray(da), jnp.asarray(db)], axis=1),
+        jnp.concatenate([jnp.asarray(ia), jnp.asarray(ib)], axis=1), k)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ci))
 
 
 def test_stage_merge_concat_matches_pairwise():
@@ -171,6 +190,5 @@ def test_stage_merge_concat_matches_pairwise():
     cd, _ = pipe.stage_merge_concat(ds_, is_, k)
     d, i = map(jnp.asarray, lists[0])
     for dn, in_ in lists[1:]:
-        d, i = pipe.stage_merge_pair(d, i, jnp.asarray(dn), jnp.asarray(in_),
-                                     use_kernel=False)
+        d, i = pipe.stage_merge_pair(d, i, jnp.asarray(dn), jnp.asarray(in_))
     np.testing.assert_array_equal(np.asarray(cd), np.asarray(d))

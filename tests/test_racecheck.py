@@ -7,7 +7,6 @@ the stock router (quiesce intact) must run the same sequence clean.
 That is the §7 contract checked dynamically instead of by source shape.
 """
 import threading
-import time
 
 import jax
 import numpy as np
@@ -159,6 +158,11 @@ def test_seeded_race_caught_without_quiesce_clean_with_it(
        violation, mutation lands;
     2. same sequence with ``_quiesce`` disabled -> ``RaceViolation`` from
        the straggler replica's token, BEFORE any WAL append.
+
+    The straggler is the victim replica's engine call held on an event
+    the test sets, so it is in flight for as long as the sequence needs,
+    whatever the machine's speed: the router's hedge deadline only decides
+    when the peer is asked, not whether the victim is still busy.
     """
     monkeypatch.setenv("REPRO_SANITIZE", "1")
     cfg, data, queries = race_setup
@@ -170,19 +174,35 @@ def test_seeded_race_caught_without_quiesce_clean_with_it(
     victim = router.replicas[0][0]
     assert hasattr(victim, "__repro_race_token__")      # ctor instrumented
     pts = data[:4].astype(np.int32)
+    release = threading.Event()
+    serve = victim.engine.run_padded
+
+    def straggle(*args, **kw):
+        release.wait()
+        return serve(*args, **kw)
+
+    victim.engine.run_padded = straggle
+    quiesce = ClusterRouter._quiesce
+
+    def release_then_quiesce(self):
+        release.set()                       # the straggler is in flight
+        quiesce(self)                       # until here; quiesce drains it
+
     try:
         # phase 1: quiesce intact — hedged straggler, then a mutation
-        victim.slow_ms = 900.0
         router._rr[0] = 0                   # pin the victim as primary
         router.query(queries)               # peer wins; straggler in flight
-        router.insert(pts)                  # _quiesce drains it first
+        assert not release.is_set()
+        with monkeypatch.context() as m:
+            m.setattr(ClusterRouter, "_quiesce", release_then_quiesce)
+            router.insert(pts)              # _quiesce drains it first
         s = router.summary()
         assert s["hedged_batches"] >= 1 and s["hedge_wins"] >= 1, s
 
         # phase 2: identical sequence, quiesce disabled.  Pin the rotation
-        # again: the hedged re-issue must land on the slow victim so its
+        # again: the hedged re-issue must land on the held victim so its
         # query is still in flight when the mutation arrives.
-        victim.slow_ms = 900.0
+        release.clear()
         router._rr[0] = 0
         router.clear_cache()                # force real dispatches
         router.query(queries)
@@ -197,9 +217,8 @@ def test_seeded_race_caught_without_quiesce_clean_with_it(
         # the violation fired at mutation ENTRY: nothing reached the WAL
         assert victim.last_seq == wal_before
     finally:
-        victim.slow_ms = 0.0
-        time.sleep(1.0)                     # let the straggler drain
-        router.close()
+        release.set()                       # let the straggler drain
+        router.close()                      # (close quiesces first)
 
 
 def test_same_thread_engine_reentrancy_is_clean_under_sanitizer(

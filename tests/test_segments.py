@@ -1,8 +1,8 @@
-"""Segmented index + staged pipeline: parity with the seed implementation.
+"""Segmented index + query path: parity with the seed implementation.
 
 Two parity guarantees (ISSUE 1 acceptance):
-  1. ``query_index`` via the staged pipeline is bit-identical to the seed
-     monolithic implementation (frozen verbatim below).
+  1. ``query_index`` (phase A + phase B at the full slab) is bit-identical
+     to the seed monolithic implementation (frozen verbatim below).
   2. A segmented index after insert + delete + compact returns the same
      top-k as a fresh ``build_index`` over the equivalent dataset.
 """
@@ -132,7 +132,7 @@ def test_single_segment_matches_query_index(cfg, small):
     state = build_index(cfg, KEY, data)
     d, i = query_index(cfg, state, queries)
     idx = SegmentedIndex.from_dataset(cfg, KEY, data)
-    d2, i2 = idx.query(queries)
+    d2, i2, _ = idx.query_compact(queries)
     np.testing.assert_array_equal(np.asarray(d), np.asarray(d2))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(i2))
 
@@ -159,7 +159,7 @@ def test_insert_delete_compact_matches_fresh_build(cfg, small):
     fresh = build_index(cfg, KEY, jnp.asarray(full[live_mask]),
                         params=idx.params)
     fd, fi = query_index(cfg, fresh, queries)
-    sd, si = idx.query(queries)
+    sd, si, _ = idx.query_compact(queries)
     np.testing.assert_array_equal(np.asarray(fd), np.asarray(sd))
     # ids differ (stable gids vs fresh row numbers) but must name the same
     # points: map fresh local ids back through the survivor gid list.
@@ -174,7 +174,7 @@ def test_multi_segment_query_finds_inserts(cfg, small):
     idx = SegmentedIndex.from_dataset(cfg, KEY, data, delta_cap=64)
     gids = idx.insert(queries)                     # spans segments + delta
     assert idx.num_segments > 1 or idx.delta_fill > 0
-    d, i = idx.query(queries)
+    d, i, _ = idx.query_compact(queries)
     d, i = np.asarray(d), np.asarray(i)
     np.testing.assert_array_equal(d[:, 0], 0)      # exact copies found
     np.testing.assert_array_equal(i[:, 0], gids)
@@ -189,7 +189,7 @@ def test_delete_is_visible_before_compaction(cfg, small):
     idx = SegmentedIndex.from_dataset(cfg, KEY, data, delta_cap=64)
     gids = idx.insert(queries)
     idx.delete(gids)                               # tombstones only
-    d, i = idx.query(queries)
+    d, i, _ = idx.query_compact(queries)
     assert not np.isin(np.asarray(i), gids).any()
     # idempotent + unknown ids ignored
     assert idx.delete(gids) == 0
@@ -205,13 +205,13 @@ def test_checkpoint_payload_roundtrip(cfg, small, tmp_path):
     idx.delete(gids[-4:])                           # kill the NEWEST gids
     payload = idx.checkpoint_payload()              # compacts first
     assert idx.num_segments == 1 and idx.num_tombstones == 0
-    d, i = idx.query(queries)
+    d, i, _ = idx.query_compact(queries)
 
     mgr = CheckpointManager(str(tmp_path), keep=1)
     mgr.save(1, payload)
     r_state, r_gids, r_next = mgr.restore(1, payload)
     node = SegmentedIndex.from_checkpoint(cfg, r_state, r_gids, r_next)
-    d2, i2 = node.query(queries)
+    d2, i2, _ = node.query_compact(queries)
     np.testing.assert_array_equal(np.asarray(d), np.asarray(d2))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(i2))
     # gid stability across restore: the deleted-then-compacted tail gids
@@ -310,23 +310,23 @@ def test_engine_cold_hits_flat_across_mutation_cycle(cfg, small):
 def test_zero_point_segment_query(cfg):
     """ISSUE 3 satellite: n=0 shards (empty seed, or delete-everything +
     compact) must answer queries with all-invalid results instead of
-    tripping the clip/gather in ``stage_candidate_gather``."""
+    tripping the clip/gather of a zero-length table."""
     dim = 16
     queries = jnp.zeros((3, dim), jnp.int32)
     empty = jnp.zeros((0, dim), jnp.int32)
 
     idx = SegmentedIndex.from_dataset(cfg, KEY, empty)
-    d, i = idx.query(queries)
+    d, i, _ = idx.query_compact(queries)
     assert (np.asarray(i) == -1).all()
 
     gids = idx.insert(np.full((2, dim), 10, np.int32))  # delta over empty seg
-    d, i = idx.query(jnp.full((1, dim), 10, jnp.int32))
+    d, i, _ = idx.query_compact(jnp.full((1, dim), 10, jnp.int32))
     assert np.asarray(d)[0, 0] == 0 and np.asarray(i)[0, 0] == gids[0]
 
     idx.delete(gids)
     idx.compact()                                   # -> zero segments
     assert idx.num_segments == 0
-    d, i = idx.query(queries)
+    d, i, _ = idx.query_compact(queries)
     assert (np.asarray(i) == -1).all()
 
     # the flat path over an empty build_index is guarded too

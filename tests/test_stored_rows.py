@@ -3,7 +3,7 @@ and the row-blocked build equals the one-shot build.
 
 Every reader of stored rows widens them back to the paper's coordinates
 (``kernels.rows.widen_rows``), so the served engine path, the flat
-two-phase query, the rerank executors, compaction, checkpoints and a
+query, the rerank, the exact scan, compaction, checkpoints and a
 replica's snapshot payload must not see the storage at all: same (dists,
 ids) bit for bit, and, where the probes reach every row, the brute-force
 answer.
@@ -18,11 +18,11 @@ import pytest
 
 from repro.ckpt import CheckpointManager
 from repro.core import index as ci
-from repro.core.index import IndexConfig, build_index, query_index_compact
-from repro.core.pipeline import l1_distance_chunked
+from repro.core.baselines import l1_distance_chunked
+from repro.core.index import IndexConfig, build_index, query_index
 from repro.core.segments import SegmentedIndex
 from repro.kernels import ref
-from repro.kernels.fused_rerank import fused_rerank_pallas, fused_rerank_xla
+from repro.kernels.fused_rerank import fused_rerank_xla
 from repro.kernels.rows import store_rows, widen_rows
 from repro.serve.engine import AnnServingEngine, ServeConfig
 
@@ -106,8 +106,8 @@ def test_flat_compact_query_uint8_equals_int32(data, name):
     s8 = build_index(_u8(cfg), KEY, jnp.asarray(x))
     assert s8.dataset.dtype == jnp.uint8
     np.testing.assert_array_equal(np.asarray(s8.dataset), x // 2)
-    got32 = query_index_compact(cfg, s32, jnp.asarray(q), floor=64)
-    got8 = query_index_compact(_u8(cfg), s8, jnp.asarray(q), floor=64)
+    got32 = query_index(cfg, s32, jnp.asarray(q))
+    got8 = query_index(_u8(cfg), s8, jnp.asarray(q))
     _same(got8, got32)
     if name == "exhaustive":
         _same(got8, _brute_force(x, np.arange(N), q, K))
@@ -120,8 +120,6 @@ def test_rerank_executors_read_uint8_rows(data):
     rows8 = store_rows(jnp.asarray(x), "uint8")
     want = ref.fused_rerank(jnp.asarray(x), jnp.asarray(q), ids, K)
     _same(fused_rerank_xla(rows8, jnp.asarray(q), ids, K, chunk=128), want)
-    _same(fused_rerank_pallas(rows8, jnp.asarray(q), ids, K,
-                              interpret=True), want)
     dedup = jnp.sort(jnp.where((ids < 0) | (ids >= N), N, ids), axis=-1)
     dedup = jnp.where(jnp.concatenate(
         [jnp.zeros((q.shape[0], 1), bool), dedup[:, 1:] == dedup[:, :-1]],

@@ -66,17 +66,6 @@ def _old_finish_segment(cfg, cbucket, c_cap, state, gids, tombstones,
     ids, _ = pipe.stage_fused_probe(
         cfg, state.sorted_keys, state.sorted_ids, probe_keys, n, cbucket,
         extents=(lo, occ), c_cap=c_cap)
-    if not pipe.rerank_handles_duplicates(cfg):
-        ids = pipe.stage_dedup(ids, n)
-    return _old_rerank(cfg, state.dataset, queries, ids, gids, tombstones)
-
-
-@partial(jax.jit, static_argnums=0)
-def _old_query_segment(cfg, state, gids, tombstones, queries):
-    n = state.dataset.shape[0]
-    ids = pipe.probe_candidates(
-        cfg, state.params, state.template, state.sorted_keys,
-        state.sorted_ids, n, queries)
     return _old_rerank(cfg, state.dataset, queries, ids, gids, tombstones)
 
 
@@ -105,6 +94,14 @@ def _tomb_array(dead):
     out = np.full((cap,), INT32_MAX, np.int32)
     out[:len(dead)] = dead
     return jnp.asarray(out)
+
+
+def _dedup(ids, n):
+    """Sorted ids, repeats -> sentinel n: each candidate once."""
+    ids = jnp.sort(ids, axis=-1)
+    dup = jnp.concatenate([jnp.zeros((ids.shape[0], 1), bool),
+                           ids[:, 1:] == ids[:, :-1]], axis=-1)
+    return jnp.where(dup, n, ids)
 
 
 def _same(a, b):
@@ -159,20 +156,20 @@ def _case(name, x, q, gids, rng):
 CASES = ["none", "top_k", "past_k", "past_slab", "repeated"]
 
 
-@pytest.mark.parametrize("impl", ["fused", "scan"])
+@pytest.mark.parametrize("slab", ["as_gathered", "deduplicated"])
 @pytest.mark.parametrize("dtype", ["int32", "uint8"])
 @pytest.mark.parametrize("case", CASES)
 def test_select_then_tombstone_equals_mask_then_rerank(data, case, dtype,
-                                                       impl):
+                                                       slab):
     x, q, _ = data
     rng = np.random.default_rng(CASES.index(case))
     gids = (3 * np.arange(N) + 7).astype(np.int32)     # gid != local row
     ids, dead = _case(case, x, q, gids, rng)
-    cfg = dataclasses.replace(CFG, rerank_impl=impl, dataset_dtype=dtype)
+    cfg = dataclasses.replace(CFG, dataset_dtype=dtype)
     rows = store_rows(jnp.asarray(x), dtype)
     ids = jnp.asarray(ids)
-    if impl == "scan":                   # the scan rerank needs unique ids
-        ids = pipe.stage_dedup(ids, N)
+    if slab == "deduplicated":     # ids as phase B hands them, or unique
+        ids = _dedup(ids, N)
     tomb = _tomb_array(dead)
     args = (cfg, rows, jnp.asarray(q), ids, jnp.asarray(gids), tomb)
     got = jax.jit(pipe.rerank_candidates, static_argnums=0)(*args)
@@ -233,17 +230,18 @@ def test_finish_segment_equals_old_order_at_every_rung(data, dtype):
         _live_gids_only(got, dead)
 
 
-@pytest.mark.parametrize("impl", ["fused", "scan"])
-def test_query_delta_drops_dead_delta_rows(data, impl):
+@pytest.mark.parametrize("fill", ["part", "full"])
+def test_query_delta_drops_dead_delta_rows(data, fill):
     x, _, fresh = data
-    cfg = dataclasses.replace(CFG, rerank_impl=impl)
-    idx = SegmentedIndex(cfg, KEY, DIM, delta_cap=128)
+    cap = {"part": 128, "full": len(fresh)}[fill]
+    idx = SegmentedIndex(CFG, KEY, DIM, delta_cap=cap)
     new = idx.insert(fresh)                   # all in the delta buffer
+    assert idx.num_segments == 0 and idx.delta_fill == len(fresh) / cap
     queries = jnp.asarray(fresh[:10])         # each one's top-1 is itself
     dead = np.concatenate([new[:10:2], new[30:50]])
     idx.delete(dead)
     pts, gids = idx._delta_arrays()
-    args = (cfg, pts, gids, jnp.int32(len(new)), idx._tombstone_array(),
+    args = (CFG, pts, gids, jnp.int32(len(new)), idx._tombstone_array(),
             queries)
     got = segments._query_delta(*args)
     _same(got, _old_query_delta(*args))
@@ -290,7 +288,6 @@ def test_engine_equals_old_order_through_mutations_and_restore(
     cfg = dataclasses.replace(CFG, dataset_dtype=dtype)
     got, dead = _serve(cfg, x, q, inserts, tmp_path / "new")
     monkeypatch.setattr(segments, "_finish_segment", _old_finish_segment)
-    monkeypatch.setattr(segments, "_query_segment", _old_query_segment)
     monkeypatch.setattr(segments, "_query_delta", _old_query_delta)
     want, _ = _serve(cfg, x, q, inserts, tmp_path / "old")
     for stage in ("sealed", "inserted", "deleted", "compacted", "restored"):
